@@ -45,9 +45,13 @@ class OuterConfig:
 
 
 def outer_init(worker_params: PyTree) -> OuterState:
-    """Outer weights start at the (identical) initial replicas."""
+    """Outer weights start at the (identical) initial replicas.
+
+    ``jnp.array`` copies: the outer weights own their buffers even when
+    the params are already float32, so a step that donates the whole
+    train state never sees one buffer twice."""
     return OuterState(
-        outer_params=jax.tree.map(lambda x: x.astype(jnp.float32),
+        outer_params=jax.tree.map(lambda x: jnp.array(x, jnp.float32),
                                   worker_params),
         momentum=jax.tree.map(lambda x: jnp.zeros_like(x, jnp.float32),
                               worker_params),

@@ -3,7 +3,7 @@
 Unlike the training-side kernels, paged attention sits on the serving hot
 path, so the non-TPU fallback is the **ref** implementation (one fused
 gather + einsum program), not interpret mode: Pallas interpret executes
-the ``slots x kv_heads x max_blocks`` grid as a Python-level loop, which
+the ``slots x max_blocks`` grid as a Python-level loop, which
 is fine for parity sweeps but orders of magnitude too slow for a decode
 tick.  The kernel-vs-ref parity tests pass ``impl="interpret"``
 explicitly.

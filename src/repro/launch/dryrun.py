@@ -39,10 +39,7 @@ def run_cell(arch_id: str, shape_name: str, *, multi_pod: bool,
     mesh_name = "multi_pod" if multi_pod else "single_pod"
     t0 = time.time()
     mesh = make_production_mesh(multi_pod=multi_pod)
-    # jax >= 0.5 spells the mesh context jax.set_mesh; on 0.4.x the Mesh
-    # object itself is the context manager.
-    set_mesh = getattr(jax, "set_mesh", None)
-    with (set_mesh(mesh) if set_mesh is not None else mesh):
+    with jax.set_mesh(mesh):
         kw = {}
         if SHAPES[shape_name].kind == "train":
             kw = {"algo": algo, "phase": phase, **cell_kw}

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from ..lint import hot_path
+from ..parallel.sharding import place_worker_axis
 
 __all__ = ["PeriodPrefetcher", "stack_period_batches"]
 
@@ -106,10 +107,12 @@ class PeriodPrefetcher:
 
     @hot_path
     def _build(self, start: int) -> PyTree:
+        # the worker axis is axis 1 of a stacked [H, W, ...] period and
+        # axis 0 of each per-step [W, ...] batch
         if self.stacked:
-            return jax.device_put(stack_period_batches(self.data, start,
-                                                       self.h))
-        return [jax.device_put(self.data.batch(r))
+            return place_worker_axis(
+                stack_period_batches(self.data, start, self.h), axis=1)
+        return [place_worker_axis(self.data.batch(r))
                 for r in range(start, start + self.h)]
 
     # -------------------------------------------------------- background

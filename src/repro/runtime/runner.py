@@ -31,11 +31,11 @@ from dataclasses import dataclass, field
 from typing import Any, Callable
 
 import jax
-import jax.numpy as jnp
 
 from ..checkpoint import CheckpointManager, reshard_workers
 from ..core.plans import SyncPlan, local_plan
 from ..lint import hot_path
+from ..parallel.sharding import place_worker_axis
 from .pipeline import PeriodPrefetcher
 from .step import (StepConfig, TrainState, compose_makeup_step,
                    make_period_step, make_train_step)
@@ -109,19 +109,23 @@ class Runner:
         self._undrained: list[tuple[int, float, dict]] = []
 
     def _build_steps(self) -> None:
-        """(Re)compile the phase-specialized steps for the current plan."""
+        """(Re)compile the phase-specialized steps for the current plan.
+
+        Every step donates its input state (``donate_argnums=0``), so a
+        phase updates the state buffers in place.  The per-step path and
+        the fused ``pipeline`` executor dispatch these SAME executables,
+        which is what makes the two bitwise-identical by construction.
+        """
         self._steps = [jax.jit(make_train_step(
-            self.model, self.optimizer, self.plan, h, cfg=self.step_cfg))
-            for h in range(self.plan.H)]
+            self.model, self.optimizer, self.plan, h, cfg=self.step_cfg),
+            donate_argnums=0) for h in range(self.plan.H)]
         # a pure local step (no sync) for straggler-skipped phases
         self._local = jax.jit(make_train_step(
             self.model, self.optimizer, local_plan(self.plan.n_units), 0,
-            cfg=self.step_cfg))
+            cfg=self.step_cfg), donate_argnums=0)
         self._makeup_cache: dict[tuple, Callable] = {}
-        # fused-path executables, built lazily on first fused run:
-        # donated clones of the phase steps ("pipeline" mode) and whole-
-        # period programs keyed by makeup-unit tuple ("compiled" mode)
-        self._donated: list[Callable] | None = None
+        # whole-period programs keyed by makeup-unit tuple ("compiled"
+        # mode), built lazily on first fused run
         self._period_cache: dict[tuple, Callable] = {}
         self._prefetch: PeriodPrefetcher | None = None
 
@@ -160,18 +164,6 @@ class Runner:
                 self.model, self.optimizer, self.plan, cfg=self.step_cfg,
                 makeup_units=makeup)
         return self._period_cache[makeup]
-
-    def _donated_steps(self) -> list[Callable]:
-        """Donated clones of the phase bodies for the fused pipeline —
-        the SAME traced programs as ``self._steps`` (bitwise-identical
-        results), re-jitted with ``donate_argnums=0`` so each phase
-        updates the state buffers in place."""
-        if self._donated is None:
-            self._donated = [jax.jit(make_train_step(
-                self.model, self.optimizer, self.plan, h,
-                cfg=self.step_cfg), donate_argnums=0)
-                for h in range(self.plan.H)]
-        return self._donated
 
     def _can_restore(self) -> bool:
         """Only swallow a failure if a checkpoint actually exists to
@@ -222,6 +214,10 @@ class Runner:
             ) -> TrainState:
         """Train; ``inject_*`` hooks are for fault-tolerance tests.
 
+        The runner takes ownership of ``state``: every step donates its
+        input buffers, so the caller's ``state`` is invalid afterwards —
+        keep the returned state instead (or pass a copy).
+
         ``fused=None`` follows ``RunnerConfig.fused_period`` — except
         when an injection hook is supplied, which drops to the per-step
         oracle (hooks address individual iterations).  Pass
@@ -252,7 +248,7 @@ class Runner:
         r = start_step
         while r < start_step + n_steps:
             phase = self.plan.phase_of_iteration(r)
-            batch = self.data.batch(r)
+            batch = place_worker_axis(self.data.batch(r))
             t0 = time.perf_counter()
             try:
                 if inject_failure_at == r:
@@ -332,9 +328,6 @@ class Runner:
                              f"'compiled', got {mode!r}")
         H = self.plan.H
         r, end = start_step, start_step + n_steps
-        # the pipeline donates the incoming state's buffers; copy once so
-        # the caller's reference stays valid (run() never donated before)
-        state = jax.tree.map(jnp.copy, state)
         stacked = mode == "compiled"
         cfg = self.run_cfg
         if self._prefetch is None or self._prefetch.data is not self.data \
@@ -384,16 +377,15 @@ class Runner:
                     fn = self._period_step(makeup)
                     state, metrics = fn(state, batch)    # async dispatch
                 else:
-                    # back-to-back async dispatch of the donated phase
-                    # clones: no host round-trip between phases, one
-                    # block at the period boundary
-                    steps = self._donated_steps()
+                    # back-to-back async dispatch of the per-step
+                    # oracle's own phase executables: no host round-trip
+                    # between phases, one block at the period boundary
                     metrics = []
                     for h in range(H):
                         if h == 0 and makeup:
                             fn = self._makeup_step(makeup)
                         else:
-                            fn = steps[h]
+                            fn = self._steps[h]
                         state, m = fn(state, batch[h])
                         metrics.append(m)
                 if r + 2 * H <= end:

@@ -32,6 +32,8 @@ from ..core.partial_sync import (UnitLayout, contiguous_ranges, divergence,
 from ..core.plans import SyncPlan, local_plan
 from ..core.sync_policies import SyncPolicy, resolve_policy
 from ..optim.optimizers import Optimizer
+from ..parallel.sharding import (constrain_worker_axis, worker_mesh,
+                                 worker_shardings)
 
 __all__ = ["TrainState", "StepConfig", "init_train_state",
            "make_train_step", "make_phase_steps", "make_period_step",
@@ -64,13 +66,26 @@ class StepConfig:
 
 def init_train_state(model, optimizer: Optimizer, key, n_workers: int,
                      *, cfg: StepConfig = StepConfig()) -> TrainState:
-    """Identical initial replicas (workers start at a sync point)."""
+    """Identical initial replicas (workers start at a sync point).
+
+    With several devices the worker axis is spread over them
+    (:func:`~repro.parallel.sharding.worker_shardings`), so a phase's
+    partial sync runs as a cross-device all-reduce.  The state is then
+    built in place: each device makes only its own replicas, and the
+    whole stack never sits on one device first."""
     from ..core.partial_sync import worker_stack
-    params = worker_stack(model.init(key), n_workers)
-    opt_state = optimizer.init(params)
-    ef, outer = resolve_policy(cfg).init_state(params)
-    return TrainState(params, opt_state, jnp.zeros((), jnp.int32), ef,
-                      outer)
+
+    def build() -> TrainState:
+        params = worker_stack(model.init(key), n_workers)
+        opt_state = optimizer.init(params)
+        ef, outer = resolve_policy(cfg).init_state(params)
+        return TrainState(params, opt_state, jnp.zeros((), jnp.int32), ef,
+                          outer)
+
+    if worker_mesh(n_workers) is None:
+        return build()
+    shardings = worker_shardings(jax.eval_shape(build))
+    return jax.jit(build, out_shardings=shardings)()
 
 
 # ---------------------------------------------------------------------------
@@ -134,7 +149,7 @@ def make_train_step(model, optimizer: Optimizer, plan: SyncPlan, phase: int,
             metrics["divergence"] = divergence(new_params)
         new_state = TrainState(new_params, new_opt, state.step + 1,
                                new_ef, new_outer)
-        return new_state, metrics
+        return constrain_worker_axis(new_state), metrics
 
     return train_step
 

@@ -1,0 +1,108 @@
+"""Compile the main path for a described TPU v5e — no chip attached.
+
+The TPU compiler is installed with JAX and compiles for a topology it is
+given as a description.  It refuses what the chip would refuse (Mosaic
+block shapes that break the (8, 128) tiling rule, programs that do not fit
+HBM), which interpret mode and the CPU backend never see.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, so under pytest-xdist the
+worker that runs this file loads it and every other worker still collects
+the same tests.  The persistent compilation cache is off around these
+compiles (an entry written for a described chip cannot be read back).
+"""
+
+from dataclasses import replace
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.api import JobConfig, Session
+from repro.configs import get_arch
+from repro.configs.granite_3_2b import CONFIG as GRANITE
+from repro.kernels.paged_attention.kernel import paged_attention_fwd
+from repro.models.transformer import DecoderLM
+from repro.optim import make_optimizer
+from repro.runtime import init_train_state, make_train_step
+
+HBM_BYTES = 16e9            # one v5e chip: 16 GB of HBM
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def topo(no_persistent_cache):
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")   # no compiler logs in /tmp
+        try:
+            return topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:                          # noqa: BLE001
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _on_chip(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+@pytest.mark.parametrize("skip_pages", [True, False])
+@pytest.mark.parametrize("arch", ["granite-3-2b", "qwen3-1.7b"])
+def test_paged_attention_compiles_for_v5e(one_chip, arch, skip_pages):
+    """The serve engine's decode kernel at the config's published GQA
+    widths: 8 slots, 16-token pages, 1088-token lanes, bf16 pages."""
+    cfg = get_arch(arch).make_model().cfg
+    slots, page, max_blocks = 8, 16, 68
+    n_pages = 1 + slots * max_blocks
+    pages = _on_chip(one_chip, (n_pages, page, cfg.n_kv_heads, cfg.hd),
+                     jnp.bfloat16)
+    args = (_on_chip(one_chip, (slots, cfg.n_heads, cfg.hd), jnp.bfloat16),
+            pages, pages,
+            _on_chip(one_chip, (slots, max_blocks), jnp.int32),
+            _on_chip(one_chip, (slots,), jnp.int32))
+    fwd = jax.jit(lambda *a: paged_attention_fwd(*a, skip_pages=skip_pages))
+    compiled = fwd.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_granite_phase_step_fits_v5e(one_chip):
+    """One granite-3-2b DreamDDP phase step at published widths (one
+    layer, W=2, one 1024-token sequence per worker) compiles for the
+    chip, donating its state, within one chip's HBM."""
+    workers, seq = 2, 1024
+    model = DecoderLM(replace(GRANITE, n_layers=1))
+    sess = Session(JobConfig(arch="granite-3-2b", smoke=False,
+                             workers=workers, period=2, seq=seq,
+                             batch_per_worker=1), model=model)
+    plan, scfg = sess.plan, sess.step_config
+    opt = make_optimizer("adam")
+    state = jax.eval_shape(lambda: init_train_state(
+        model, opt, jax.random.PRNGKey(0), workers, cfg=scfg))
+    state = jax.tree.map(lambda s: _on_chip(one_chip, s.shape, s.dtype),
+                         state)
+    batch = {k: _on_chip(one_chip, (workers, 1, seq), jnp.int32)
+             for k in ("tokens", "labels")}
+    phase = next(h for h in range(plan.H) if plan.units_for_phase(h))
+    step = jax.jit(make_train_step(model, opt, plan, phase, cfg=scfg),
+                   donate_argnums=0)
+    mem = step.lower(state, batch).compile().memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert mem.alias_size_in_bytes > 0          # the state is donated
+    assert total < HBM_BYTES, total
