@@ -39,6 +39,7 @@ from ..data import MarkovCorpus
 from ..optim import make_optimizer
 from ..runtime import (Runner, RunnerConfig, StepConfig, TrainState,
                        init_train_state)
+from ..runtime import spans
 from ..runtime.runner import reshard_train_state
 from ..serve import EngineConfig, ServeEngine
 from .registry import get_strategy
@@ -296,21 +297,22 @@ class Session:
         log is a deterministic function of the total period count, so a
         session runs exactly one async timeline — call ``fit`` once.
         """
-        self._ensure_built()
-        if self.use_async:
-            H = self.cfg.period
-            if steps % H:
-                raise ValueError(
-                    f"async fit advances whole periods: steps={steps} is "
-                    f"not a multiple of H={H}")
-            self._runner.run((self._step + steps) // H)
+        with spans.span(spans.FIT, self._step):
+            self._ensure_built()
+            if self.use_async:
+                H = self.cfg.period
+                if steps % H:
+                    raise ValueError(
+                        f"async fit advances whole periods: steps={steps} "
+                        f"is not a multiple of H={H}")
+                self._runner.run((self._step + steps) // H)
+                self._step += steps
+                self._state = self._state._replace(
+                    params=self._runner.stacked_params(self.cfg.workers))
+                return self
+            self._state = self._runner.run(self._state, steps,
+                                           start_step=self._step)
             self._step += steps
-            self._state = self._state._replace(
-                params=self._runner.stacked_params(self.cfg.workers))
-            return self
-        self._state = self._runner.run(self._state, steps,
-                                       start_step=self._step)
-        self._step += steps
         return self
 
     # ------------------------------------------------------------- replan

@@ -18,8 +18,7 @@ from .plans import (ALGOS, SyncPlan, build_plan, local_plan,
 from .sync_policies import (Int8EFSync, MeanSync, OuterOptSync, SyncPolicy,
                             resolve_policy)
 from .profiler import (A6000_CLUSTER, GEO_WAN, V5E, HardwareSpec, LayerCost,
-                       LayerProfile, analytic_profile, measured_profile,
-                       ring_allreduce_time)
+                       LayerProfile, analytic_profile, ring_allreduce_time)
 from .schedule import (ScheduleResult, SearchStats, brute_force_count,
                        brute_force_schedule, dreamddp_schedule, enp_schedule)
 from .time_model import (Partition, PhaseTimeline, ascwfbp_iteration_time,

@@ -1,33 +1,31 @@
 """Layer-wise communication/computation profiler (paper §3, Fig. 4 "Profiler").
 
 DreamDDP's scheduler consumes per-layer backward times ``t_BP^l`` and
-parameter-synchronization times ``t_COMM^l``.  Two sources are provided:
+parameter-synchronization times ``t_COMM^l``.  :func:`analytic_profile`
+derives them from per-layer FLOP/byte counts and a :class:`HardwareSpec`
+roofline (also for the paper's bandwidth-sweep experiments).  The train
+step's named scopes (``fwd``, ``optimizer``, ``sync``; ``runtime/step.py``)
+let a profiler trace split measured device time into forward, backward,
+remat recompute and optimizer; no profile is built from them yet.
 
-* :func:`analytic_profile` — derives times from per-layer FLOP/byte counts and
-  a :class:`HardwareSpec` roofline (used on this CPU-only container, where the
-  TPU is the *target*, and for the paper's bandwidth-sweep experiments).
-* :func:`measured_profile` — times real per-layer forward/backward on the
-  attached backend (used on hardware; also exercised in tests on CPU).
-
-Both produce a :class:`LayerProfile`, the scheduler's only input — so the
-schedule is *data*, recomputable when bandwidth changes (paper §6 limitation:
-we expose :meth:`LayerProfile.with_bandwidth` for cheap re-profiling).
+The result is a :class:`LayerProfile`, the scheduler's only
+input — so the schedule is *data*, recomputable when bandwidth changes
+(paper §6 limitation: we expose :meth:`LayerProfile.with_bandwidth` for
+cheap re-profiling).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import json
-import time
 from dataclasses import dataclass, field
-from typing import Callable, Sequence
+from typing import Sequence
 
 __all__ = [
     "HardwareSpec",
     "LayerCost",
     "LayerProfile",
     "analytic_profile",
-    "measured_profile",
     "ring_allreduce_time",
     "V5E",
     "A6000_CLUSTER",
@@ -191,33 +189,3 @@ def analytic_profile(
         ))
     return LayerProfile(layers, hw)
 
-
-def measured_profile(
-    layer_fns: Sequence[tuple[str, Callable[[], object], float]],
-    hw: HardwareSpec,
-    *,
-    warmup: int = 2,
-    iters: int = 5,
-) -> LayerProfile:
-    """Time per-layer fwd+bwd thunks on the attached backend.
-
-    ``layer_fns`` is ``(name, thunk, param_bytes)``; each thunk runs one
-    fwd+bwd of that layer and blocks until ready.  We split the measured
-    wall time into t_fp/t_bp with the spec's ``bwd_fwd_ratio``; t_comm is
-    still model-derived (measuring a WAN link is deployment-specific).
-    """
-    layers = []
-    for name, thunk, param_bytes in layer_fns:
-        for _ in range(warmup):
-            thunk()
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            thunk()
-        dt = (time.perf_counter() - t0) / iters
-        r = hw.bwd_fwd_ratio
-        t_fp = dt / (1.0 + r)
-        layers.append(LayerCost(
-            name=name, param_bytes=param_bytes, t_fp=t_fp, t_bp=t_fp * r,
-            t_comm=ring_allreduce_time(param_bytes, hw),
-        ))
-    return LayerProfile(layers, hw)

@@ -118,6 +118,7 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q,), jnp.float32),
         ],
         interpret=interpret,
+        name="repro_flash_attention",
     )(qf, kf, vf)
 
     out = out[:, :sq].reshape(b, n_q, sq, hd)

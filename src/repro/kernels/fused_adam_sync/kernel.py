@@ -78,6 +78,7 @@ def fused_adamw(p: jax.Array, g: jax.Array, m: jax.Array, v: jax.Array, *,
             jax.ShapeDtypeStruct(vf.shape, jnp.float32),
         ],
         interpret=interpret,
+        name="repro_fused_adam",
     )(hyper, pf, gf, mf, vf)
     unflat = lambda x, dt: x[:n].reshape(shape).astype(dt)
     return unflat(p2, dtype), unflat(m2, jnp.float32), \

@@ -48,6 +48,7 @@ def quantize_rows(x: jax.Array, *, block_r: int = 256,
         out_shape=[jax.ShapeDtypeStruct((rp, c), jnp.int8),
                    jax.ShapeDtypeStruct((rp, 1), jnp.float32)],
         interpret=interpret,
+        name="repro_int8_quant",
     )(x)
     return q[:r], s[:r]
 
@@ -68,5 +69,6 @@ def dequantize_rows(q: jax.Array, s: jax.Array, *, dtype=jnp.float32,
         out_specs=pl.BlockSpec((block_r, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rp, c), dtype),
         interpret=interpret,
+        name="repro_int8_dequant",
     )(q, s)
     return x[:r]

@@ -160,5 +160,6 @@ def paged_attention_fwd(q: jax.Array, k_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((slots, n_q, hd), q.dtype),
         interpret=interpret,
+        name="repro_paged_attention",
     )(block_tables.astype(jnp.int32), kv_len.astype(jnp.int32),
       q, k_pages, v_pages)

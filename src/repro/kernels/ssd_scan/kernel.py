@@ -75,5 +75,6 @@ def ssd_chunk_fwd(x: jax.Array, b: jax.Array, c: jax.Array,
             jax.ShapeDtypeStruct((B, NC, H, p, n), jnp.float32),
         ],
         interpret=interpret,
+        name="repro_ssd_scan",
     )(x, b, c, da)
     return y, s

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 
 from ..lint import hot_path
 from ..parallel.sharding import place_worker_axis
+from . import spans
 
 __all__ = ["PeriodPrefetcher", "stack_period_batches"]
 
@@ -132,7 +133,8 @@ class PeriodPrefetcher:
                 slot.fail(RuntimeError("prefetch invalidated"))
                 continue
             try:
-                slot.fill(self._build(start))
+                with spans.span(spans.STAGE, start):
+                    slot.fill(self._build(start))
             except BaseException as e:              # surfaced in take()
                 slot.fail(e)
 

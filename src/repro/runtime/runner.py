@@ -36,6 +36,7 @@ from ..checkpoint import CheckpointManager, reshard_workers
 from ..core.plans import SyncPlan, local_plan
 from ..lint import hot_path
 from ..parallel.sharding import place_worker_axis
+from . import spans
 from .pipeline import PeriodPrefetcher
 from .step import (StepConfig, TrainState, compose_makeup_step,
                    make_period_step, make_train_step)
@@ -120,9 +121,11 @@ class Runner:
             self.model, self.optimizer, self.plan, h, cfg=self.step_cfg),
             donate_argnums=0) for h in range(self.plan.H)]
         # a pure local step (no sync) for straggler-skipped phases
-        self._local = jax.jit(make_train_step(
-            self.model, self.optimizer, local_plan(self.plan.n_units), 0,
-            cfg=self.step_cfg), donate_argnums=0)
+        local = make_train_step(self.model, self.optimizer,
+                                local_plan(self.plan.n_units), 0,
+                                cfg=self.step_cfg)
+        local.__name__ = local.__qualname__ = "local_step"
+        self._local = jax.jit(local, donate_argnums=0)
         self._makeup_cache: dict[tuple, Callable] = {}
         # whole-period programs keyed by makeup-unit tuple ("compiled"
         # mode), built lazily on first fused run
@@ -190,7 +193,8 @@ class Runner:
         a drain costs a single host round-trip regardless of cadence."""
         if not self._undrained:
             return
-        drained = jax.device_get([m for _, _, m in self._undrained])
+        with spans.span(spans.DRAIN, self._undrained[0][0]):
+            drained = jax.device_get([m for _, _, m in self._undrained])
         for (r0, dt, _), metrics in zip(self._undrained, drained, strict=True):
             if isinstance(metrics, list):      # pipeline: H per-phase dicts
                 host = [{k: float(v) for k, v in m.items()}
@@ -247,63 +251,70 @@ class Runner:
                       ) -> TrainState:
         r = start_step
         while r < start_step + n_steps:
-            phase = self.plan.phase_of_iteration(r)
-            batch = place_worker_axis(self.data.batch(r))
-            t0 = time.perf_counter()
-            try:
-                if inject_failure_at == r:
-                    inject_failure_at = None
-                    raise RuntimeError("injected node failure")
+            with spans.step_span(spans.STEP, r):
+                phase = self.plan.phase_of_iteration(r)
+                batch = place_worker_axis(self.data.batch(r))
+                t0 = time.perf_counter()
+                try:
+                    if inject_failure_at == r:
+                        inject_failure_at = None
+                        raise RuntimeError("injected node failure")
 
-                if self.pending_units and phase == 0:
-                    fn = self._makeup_step(tuple(sorted(self.pending_units)))
-                    self.pending_units.clear()
-                else:
-                    fn = self._steps[phase]
-                state, metrics = fn(state, batch)
-                # block on the COMPLETED step — params included — before
-                # stamping the deadline clock.  Blocking only on the loss
-                # (the old behaviour) measured dispatch + forward but let
-                # the phase's parameter all-reduce keep running, so a
-                # stalled link never tripped `deadline_factor`.
-                jax.block_until_ready((state, metrics))
-            except Exception:                         # noqa: BLE001
-                if not self._can_restore():
-                    raise
-                self.retries += 1
-                r0, state, _ = self._restore_into(state)
-                r = r0
-                continue
+                    if self.pending_units and phase == 0:
+                        fn = self._makeup_step(
+                            tuple(sorted(self.pending_units)))
+                        self.pending_units.clear()
+                    else:
+                        fn = self._steps[phase]
+                    state, metrics = fn(state, batch)
+                    # block on the COMPLETED step — params included —
+                    # before stamping the deadline clock.  Blocking only
+                    # on the loss (the old behaviour) measured dispatch +
+                    # forward but let the phase's parameter all-reduce
+                    # keep running, so a stalled link never tripped
+                    # `deadline_factor`.
+                    jax.block_until_ready((state, metrics))
+                except Exception:                         # noqa: BLE001
+                    if not self._can_restore():
+                        raise
+                    self.retries += 1
+                    r0, state, _ = self._restore_into(state)
+                    r = r0
+                    continue
 
-            dt = time.perf_counter() - t0
-            if inject_straggler_at is not None and inject_straggler_at[0] == r:
-                dt += inject_straggler_at[1]
-                inject_straggler_at = None
-            # straggler policy: if this was a sync phase and it blew the
-            # deadline, requeue its units and remember to skip-equivalent
-            # (the sync already happened here; the policy matters when the
-            # *link* stalls — we model it by requeueing the NEXT occurrence)
-            if (len(self._times) >= self.run_cfg.min_history
-                    and self.plan.is_parameter_sync
-                    and self.plan.units_for_phase(phase)
-                    and dt > self.run_cfg.deadline_factor
-                    * self._median_time()):
-                self.pending_units.update(self.plan.units_for_phase(phase))
-                self.skipped_syncs += 1
-            self._times.append(dt)
+                dt = time.perf_counter() - t0
+                if inject_straggler_at is not None and \
+                        inject_straggler_at[0] == r:
+                    dt += inject_straggler_at[1]
+                    inject_straggler_at = None
+                # straggler policy: if this was a sync phase and it blew
+                # the deadline, requeue its units and remember to
+                # skip-equivalent (the sync already happened here; the
+                # policy matters when the *link* stalls — we model it by
+                # requeueing the NEXT occurrence)
+                if (len(self._times) >= self.run_cfg.min_history
+                        and self.plan.is_parameter_sync
+                        and self.plan.units_for_phase(phase)
+                        and dt > self.run_cfg.deadline_factor
+                        * self._median_time()):
+                    self.pending_units.update(
+                        self.plan.units_for_phase(phase))
+                    self.skipped_syncs += 1
+                self._times.append(dt)
 
-            # the block above already synced; one device_get batches the
-            # (cheap, already-computed) metric transfers per step
-            row = jax.device_get(metrics)
-            self.history.append({"step": r, "phase": phase,
-                                 "time": dt,
-                                 **{k: float(v) for k, v in
-                                    row.items()}})
-            if self.ckpt is not None and (r + 1) % \
-                    self.run_cfg.ckpt_every == 0:
-                self.ckpt.save(r + 1, state,
-                               meta={"plan": self.plan.to_json()})
-            r += 1
+                # the block above already synced; one device_get batches the
+                # (cheap, already-computed) metric transfers per step
+                row = jax.device_get(metrics)
+                self.history.append({"step": r, "phase": phase,
+                                     "time": dt,
+                                     **{k: float(v) for k, v in
+                                        row.items()}})
+                if self.ckpt is not None and (r + 1) % \
+                        self.run_cfg.ckpt_every == 0:
+                    with spans.span(spans.CHECKPOINT, r):
+                        self.ckpt.save(r + 1, state,
+                                       meta={"plan": self.plan.to_json()})
+                r += 1
         if self.ckpt is not None:
             self.ckpt.wait()
         return state
@@ -362,77 +373,84 @@ class Runner:
                 r += n
                 continue
 
-            batch = pipe.get(r)
-            t0 = time.perf_counter()
-            try:
-                if in_period(inject_failure_at):
-                    inject_failure_at = None
-                    raise RuntimeError("injected node failure")
+            with spans.step_span(spans.PERIOD, r):
+                with spans.span(spans.STAGE, r):
+                    batch = pipe.get(r)
+                t0 = time.perf_counter()
+                try:
+                    if in_period(inject_failure_at):
+                        inject_failure_at = None
+                        raise RuntimeError("injected node failure")
 
-                makeup = ()
-                if self.pending_units:
-                    makeup = tuple(sorted(self.pending_units))
-                    self.pending_units.clear()
-                if mode == "compiled":
-                    fn = self._period_step(makeup)
-                    state, metrics = fn(state, batch)    # async dispatch
-                else:
-                    # back-to-back async dispatch of the per-step
-                    # oracle's own phase executables: no host round-trip
-                    # between phases, one block at the period boundary
-                    metrics = []
-                    for h in range(H):
-                        if h == 0 and makeup:
-                            fn = self._makeup_step(makeup)
+                    makeup = ()
+                    if self.pending_units:
+                        makeup = tuple(sorted(self.pending_units))
+                        self.pending_units.clear()
+                    with spans.span(spans.DISPATCH, r):
+                        if mode == "compiled":
+                            fn = self._period_step(makeup)
+                            state, metrics = fn(state, batch)  # async dispatch
                         else:
-                            fn = self._steps[h]
-                        state, m = fn(state, batch[h])
-                        metrics.append(m)
-                if r + 2 * H <= end:
-                    # stage p+1..p+depth under p's compute; never past
-                    # the last full period of this run
-                    pipe.prefetch(r + H, last=end - H)
-                # blocking on (state, metrics) times the COMPLETED period
-                # — parameter syncs included — with one host sync per H
-                # steps instead of per step
-                jax.block_until_ready((state, metrics))
-            except Exception:                         # noqa: BLE001
-                if not self._can_restore():
-                    raise
-                self.retries += 1
-                self._drain_metrics()
-                pipe.invalidate()
-                r0, state, _ = self._restore_into(state)
-                r = r0
-                continue
+                            # back-to-back async dispatch of the per-step
+                            # oracle's own phase executables: no host
+                            # round-trip between phases, one block at the
+                            # period boundary
+                            metrics = []
+                            for h in range(H):
+                                if h == 0 and makeup:
+                                    fn = self._makeup_step(makeup)
+                                else:
+                                    fn = self._steps[h]
+                                state, m = fn(state, batch[h])
+                                metrics.append(m)
+                    if r + 2 * H <= end:
+                        # stage p+1..p+depth under p's compute; never past
+                        # the last full period of this run
+                        with spans.span(spans.STAGE, r):
+                            pipe.prefetch(r + H, last=end - H)
+                    # blocking on (state, metrics) times the COMPLETED period
+                    # — parameter syncs included — with one host sync per H
+                    # steps instead of per step
+                    with spans.span(spans.WAIT, r):
+                        jax.block_until_ready((state, metrics))
+                except Exception:                         # noqa: BLE001
+                    if not self._can_restore():
+                        raise
+                    self.retries += 1
+                    self._drain_metrics()
+                    pipe.invalidate()
+                    r0, state, _ = self._restore_into(state)
+                    r = r0
+                    continue
 
-            dt = time.perf_counter() - t0
-            if inject_straggler_at is not None and \
-                    in_period(inject_straggler_at[0]):
-                dt += inject_straggler_at[1]
-                inject_straggler_at = None
-            # straggler deadline at period granularity: a blown period
-            # can't be attributed to one phase from outside the
-            # executable, so every unit the period syncs is re-queued
-            # for make-up (a superset of the oracle's requeue — extra
-            # syncs only tighten Lemma 4's staleness bound)
-            if (len(self.period_times) >= self.run_cfg.min_history
-                    and self.plan.is_parameter_sync
-                    and dt > self.run_cfg.deadline_factor
-                    * self._median_period_time()):
-                self.pending_units.update(self.plan.all_sync_units())
-                self.skipped_syncs += 1
-            self.period_times.append(dt)
+                dt = time.perf_counter() - t0
+                if inject_straggler_at is not None and \
+                        in_period(inject_straggler_at[0]):
+                    dt += inject_straggler_at[1]
+                    inject_straggler_at = None
+                # straggler deadline at period granularity: a blown period
+                # can't be attributed to one phase from outside the
+                # executable, so every unit the period syncs is re-queued
+                # for make-up (a superset of the oracle's requeue — extra
+                # syncs only tighten Lemma 4's staleness bound)
+                if (len(self.period_times) >= self.run_cfg.min_history
+                        and self.plan.is_parameter_sync
+                        and dt > self.run_cfg.deadline_factor
+                        * self._median_period_time()):
+                    self.pending_units.update(self.plan.all_sync_units())
+                    self.skipped_syncs += 1
+                self.period_times.append(dt)
 
-            self._undrained.append((r, dt, metrics))
-            if len(self._undrained) >= self.run_cfg.log_every:
-                self._drain_metrics()
-            if self.ckpt is not None and \
-                    (r + H) // self.run_cfg.ckpt_every > \
-                    r // self.run_cfg.ckpt_every:
-                self.ckpt.save(r + H, state,
-                               meta={"plan": self.plan.to_json()})
-            r += H
+                self._undrained.append((r, dt, metrics))
+                if len(self._undrained) >= self.run_cfg.log_every:
+                    self._drain_metrics()
+                if self.ckpt is not None and \
+                        (r + H) // self.run_cfg.ckpt_every > \
+                        r // self.run_cfg.ckpt_every:
+                    with spans.span(spans.CHECKPOINT, r):
+                        self.ckpt.save(r + H, state,
+                                       meta={"plan": self.plan.to_json()})
+                r += H
         self._drain_metrics()
         if self.ckpt is not None:
             self.ckpt.wait()

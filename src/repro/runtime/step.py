@@ -19,7 +19,6 @@ branching here.
 
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass, field
 from typing import Any, NamedTuple
 
@@ -104,7 +103,16 @@ def _cuts_for(units, layout: UnitLayout) -> tuple[int, ...]:
 def make_train_step(model, optimizer: Optimizer, plan: SyncPlan, phase: int,
                     *, cfg: StepConfig = StepConfig(),
                     donate: bool = True):
-    """Build the jittable step for one phase (phase is STATIC)."""
+    """Build the jittable step for one phase (phase is STATIC).
+
+    The step is named ``phase_<phase>`` (its jitted module is
+    ``jit_phase_<phase>``), and its parts run under named scopes that
+    the compiled HLO's ``op_name`` metadata carries: ``fwd`` around the
+    differentiated loss (forward ``jvp(fwd)``, backward
+    ``transpose(jvp(fwd))``, remat recompute beneath that in
+    ``rematted_computation``), ``optimizer`` around the update (clip
+    included) and ``sync`` around the gradient mean and the parameter
+    sync."""
     layout = model.unit_layout()
     units = plan.units_for_phase(phase)
     cuts = _cuts_for(units, layout) if cfg.segment_cuts else ()
@@ -115,7 +123,10 @@ def make_train_step(model, optimizer: Optimizer, plan: SyncPlan, phase: int,
         arrives PRE-microbatched ``[n_micro, B_micro, ...]`` (the data
         pipeline / cell builder adds the axis, keeping shardings static
         through the accumulation scan)."""
-        loss_fn = functools.partial(model.loss, segment_cuts=cuts)
+        def loss_fn(params, batch):
+            with jax.named_scope("fwd"):
+                return model.loss(params, batch, segment_cuts=cuts)
+
         if cfg.n_microbatches == 1:
             return jax.value_and_grad(loss_fn)(params, batch)
 
@@ -137,20 +148,24 @@ def make_train_step(model, optimizer: Optimizer, plan: SyncPlan, phase: int,
         metrics = {"loss": jnp.mean(losses)}
 
         if not plan.is_parameter_sync:
-            grads = tree_worker_mean(grads)      # DDP: gradient all-reduce
+            with jax.named_scope("sync"):
+                grads = tree_worker_mean(grads)  # DDP: gradient all-reduce
 
-        new_params, new_opt = optimizer.update(grads, state.opt_state,
-                                               state.params, state.step)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optimizer.update(grads, state.opt_state,
+                                                   state.params, state.step)
         new_ef, new_outer = state.ef, state.outer
         if plan.is_parameter_sync and units:
-            new_params, new_ef, new_outer = policy.apply(
-                new_params, state.ef, state.outer, units, layout)
+            with jax.named_scope("sync"):
+                new_params, new_ef, new_outer = policy.apply(
+                    new_params, state.ef, state.outer, units, layout)
         if cfg.track_divergence:
             metrics["divergence"] = divergence(new_params)
         new_state = TrainState(new_params, new_opt, state.step + 1,
                                new_ef, new_outer)
         return constrain_worker_axis(new_state), metrics
 
+    train_step.__name__ = train_step.__qualname__ = f"phase_{phase}"
     return train_step
 
 
@@ -168,12 +183,13 @@ def compose_makeup_step(local_step, units, layout: UnitLayout):
     """
     units = tuple(sorted(units))
 
-    def makeup(state: TrainState, batch: PyTree):
+    def makeup_step(state: TrainState, batch: PyTree):
         new_state, m = local_step(state, batch)
-        return new_state._replace(
-            params=sync_units(new_state.params, list(units), layout)), m
+        with jax.named_scope("sync"):
+            params = sync_units(new_state.params, list(units), layout)
+        return new_state._replace(params=params), m
 
-    return makeup
+    return makeup_step
 
 
 def make_period_step(model, optimizer: Optimizer, plan: SyncPlan, *,

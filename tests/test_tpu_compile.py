@@ -67,18 +67,32 @@ def _on_chip(one_chip, shape, dtype):
 def test_paged_attention_compiles_for_v5e(one_chip, arch, skip_pages):
     """The serve engine's decode kernel at the config's published GQA
     widths: 8 slots, 16-token pages, 1088-token lanes, bf16 pages."""
+    args = _paged_args(one_chip, arch)
+    fwd = jax.jit(lambda *a: paged_attention_fwd(*a, skip_pages=skip_pages))
+    compiled = fwd.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_attention_kernel_carries_its_name(one_chip):
+    """The kernel's name reaches the program the chip runs, where a
+    device trace can find it."""
+    lowered = jax.jit(paged_attention_fwd).lower(
+        *_paged_args(one_chip, "granite-3-2b"))
+    assert "repro_paged_attention" in lowered.as_text()
+
+
+def _paged_args(one_chip, arch):
+    """Decode-kernel inputs at the config's published GQA widths: 8
+    slots, 16-token pages, 1088-token lanes, bf16 pages."""
     cfg = get_arch(arch).make_model().cfg
     slots, page, max_blocks = 8, 16, 68
     n_pages = 1 + slots * max_blocks
     pages = _on_chip(one_chip, (n_pages, page, cfg.n_kv_heads, cfg.hd),
                      jnp.bfloat16)
-    args = (_on_chip(one_chip, (slots, cfg.n_heads, cfg.hd), jnp.bfloat16),
+    return (_on_chip(one_chip, (slots, cfg.n_heads, cfg.hd), jnp.bfloat16),
             pages, pages,
             _on_chip(one_chip, (slots, max_blocks), jnp.int32),
             _on_chip(one_chip, (slots,), jnp.int32))
-    fwd = jax.jit(lambda *a: paged_attention_fwd(*a, skip_pages=skip_pages))
-    compiled = fwd.lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_granite_phase_step_fits_v5e(one_chip):
