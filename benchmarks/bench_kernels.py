@@ -37,7 +37,7 @@ def run(csv: bool = True) -> list[dict]:
                           jnp.bfloat16)
     v = jax.random.normal(jax.random.PRNGKey(2), (b, s, nkv, hd),
                           jnp.bfloat16)
-    out = flash_attention(q, k, v, block_q=128, block_k=128)
+    out = flash_attention(q, k, v)
     ref = attention_ref(q, k, v)
     io = (q.size + 2 * k.size + out.size) * 2
     naive = io + b * nq * s * s * 4 * 2          # fp32 scores r+w

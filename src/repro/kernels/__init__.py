@@ -1,6 +1,7 @@
 """Pallas TPU kernels for the compute hot spots (interpret-validated on CPU).
 
-flash_attention  blockwise causal GQA attention forward (prefill hot path)
+flash_attention  causal GQA flash attention with its backward (JAX's
+                 splash kernels): the training path's attention on TPU
 paged_attention  block-table decode attention over a paged KV pool
                  (serve engine kv_backend="paged" hot path)
 fused_adam_sync  one-pass fused AdamW update (HBM-bound optimizer step)

@@ -19,13 +19,15 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ..kernels.flash_attention import flash_attention
+
 __all__ = [
     "Init",
     "dense_init", "dense",
     "norm_init", "rms_norm", "layer_norm",
     "embed_init",
     "rope_freqs", "apply_rope",
-    "gqa_attention",
+    "gqa_attention", "causal_self_attention",
     "mlp_init", "mlp_apply",
     "softmax_xent",
     "count_params",
@@ -216,6 +218,27 @@ def gqa_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                           (jnp.moveaxis(qs, 1, 0), jnp.moveaxis(ps, 1, 0)))
     out = jnp.moveaxis(out, 0, 1).reshape(b, -1, n_q, hd)
     return out[:, :sq]
+
+
+def causal_self_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                          positions: jax.Array) -> jax.Array:
+    """Causal GQA self-attention of a sequence over its own positions:
+    ``positions [b, s]`` must be ``0 .. s-1`` in every row (shapes as
+    :func:`gqa_attention`).
+
+    Where the program lowers for TPU this is the flash kernel, forward
+    and backward, which needs no positions; elsewhere it is
+    :func:`gqa_attention` over ``positions``.  The branch is chosen at
+    lowering, so a compile for a described TPU takes the kernel too."""
+    def flash(q, k, v, _):
+        return flash_attention(q, k, v)
+
+    def einsum(q, k, v, positions):
+        return gqa_attention(q, k, v, q_positions=positions,
+                             kv_positions=positions)
+
+    return jax.lax.platform_dependent(q, k, v, positions, tpu=flash,
+                                      default=einsum)
 
 
 # ---------------------------------------------------------------------------

@@ -26,9 +26,10 @@ from ..core.partial_sync import UnitEntry, UnitLayout
 from ..kernels.paged_attention import paged_attention, write_token_to_pages
 from . import mla as mla_mod
 from . import moe as moe_mod
-from .layers import (Init, apply_rope, dense, dense_init, embed_init,
-                     gqa_attention, layer_norm, mlp_apply, mlp_init,
-                     norm_init, rms_norm, rope_freqs, softmax_xent)
+from .layers import (Init, apply_rope, causal_self_attention, dense,
+                     dense_init, embed_init, gqa_attention, layer_norm,
+                     mlp_apply, mlp_init, norm_init, rms_norm, rope_freqs,
+                     softmax_xent)
 
 __all__ = ["LMConfig", "DecoderLM"]
 
@@ -54,7 +55,6 @@ class LMConfig:
     window: int | None = None             # local attention window
     param_dtype: str = "bfloat16"
     remat: bool = True
-    attn_impl: str = "einsum"             # or "flash" (Pallas kernel)
     # MoE
     moe: moe_mod.MoEConfig | None = None
     n_dense_layers: int = 0               # leading dense layers (dsv3: 3)
@@ -225,8 +225,16 @@ class DecoderLM:
         k = apply_rope(k, positions, inv_freq)
         return q, k, v
 
-    def _attend(self, p, x, positions, cache, write_pos):
-        """Attention sub-layer; returns (out, new_cache_entry)."""
+    def _attend(self, p, x, positions, cache, write_pos,
+                own_positions=False):
+        """Attention sub-layer; returns (out, new_cache_entry).
+
+        ``own_positions``: ``positions`` are the sequence's own ``0 ..
+        s-1``, built by :meth:`_backbone`.  Uncached, unwindowed
+        attention over them is
+        :func:`~repro.models.layers.causal_self_attention` (the flash
+        kernel on TPU); explicit positions, a cache or a window take
+        :func:`~repro.models.layers.gqa_attention`."""
         cfg = self.cfg
         if cfg.mla is not None:
             if cache is None:
@@ -247,7 +255,10 @@ class DecoderLM:
         b, s, _ = x.shape
         q, k, v = self._project_qkv(p, x, positions)
 
-        if cache is None:
+        if cache is None and own_positions and cfg.window is None:
+            out = causal_self_attention(q, k, v, positions)
+            new_cache = None
+        elif cache is None:
             out = gqa_attention(q, k, v, q_positions=positions,
                                 kv_positions=positions, causal=True,
                                 window=cfg.window)
@@ -272,9 +283,10 @@ class DecoderLM:
                 else layer_norm(p, x))
 
     def _block_apply(self, kind: str, p, x, positions, cache=None,
-                     write_pos=None):
+                     write_pos=None, own_positions=False):
         a, new_cache = self._attend(p["attn"], self._norm(p["ln1"], x),
-                                    positions, cache, write_pos)
+                                    positions, cache, write_pos,
+                                    own_positions)
         x = x + a
         h = self._norm(p["ln2"], x)
         if kind == "moe":
@@ -284,7 +296,7 @@ class DecoderLM:
         return x, new_cache
 
     def _run_stack(self, kind, stacked, x, positions, cache=None,
-                   write_pos=None, cuts=()):
+                   write_pos=None, cuts=(), own_positions=False):
         """Scan a block stack over its layer axis, split at ``cuts``."""
         n = jax.tree_util.tree_leaves(stacked)[0].shape[0]
         bounds = sorted({0, n, *[c for c in cuts if 0 < c < n]})
@@ -299,8 +311,9 @@ class DecoderLM:
                 lp, lc = xs
                 fn = self._block_apply
                 if self.cfg.remat and cache is None:
-                    fn = jax.checkpoint(fn, static_argnums=(0,))
-                y, nc = fn(kind, lp, carry, positions, lc, write_pos)
+                    fn = jax.checkpoint(fn, static_argnums=(0, 6))
+                y, nc = fn(kind, lp, carry, positions, lc, write_pos,
+                           own_positions)
                 return y, nc
 
             x, new_c = jax.lax.scan(body, x, (seg, seg_cache))
@@ -333,18 +346,22 @@ class DecoderLM:
 
         ``segment_cuts`` are *global unit ids* (layout order) at which block
         stacks are split into separate scans (DreamDDP overlap windows).
+        Without ``positions`` each token sits at its index (see
+        :meth:`_attend`).
         """
         cfg = self.cfg
         x = self._embed(params, tokens, embeds)
         b, s, _ = x.shape
-        if positions is None:
+        own_positions = positions is None
+        if own_positions:
             positions = jnp.broadcast_to(jnp.arange(s), (b, s))
         unit0 = 1                        # unit 0 is the embedding
         for group, kind, n in cfg.runs():
             local_cuts = tuple(c - unit0 for c in segment_cuts
                                if unit0 < c < unit0 + n)
             x, _ = self._run_stack(kind, params[group], x, positions,
-                                   cuts=local_cuts)
+                                   cuts=local_cuts,
+                                   own_positions=own_positions)
             unit0 += n
         return x
 

@@ -142,9 +142,23 @@ def make_train_step(model, optimizer: Optimizer, plan: SyncPlan, phase: int,
         inv = 1.0 / cfg.n_microbatches
         return loss * inv, jax.tree.map(lambda g: g * inv, grads)
 
+    def worker_grads(params, batch):
+        """Every worker's loss and grads.  With the worker axis spread
+        over devices, each device runs its own workers under
+        ``shard_map``: worker-local work needs no collective, and the
+        Pallas kernels inside (which XLA cannot partition) see one
+        device's share."""
+        grads_fn = jax.vmap(per_worker_grads)
+        mesh = worker_mesh(jax.tree_util.tree_leaves(params)[0].shape[0])
+        if mesh is None:
+            return grads_fn(params, batch)
+        spec = jax.sharding.PartitionSpec("data")
+        return jax.shard_map(grads_fn, mesh=mesh, in_specs=(spec, spec),
+                             out_specs=spec, check_vma=False)(params, batch)
+
     def train_step(state: TrainState, batch: PyTree
                    ) -> tuple[TrainState, dict]:
-        losses, grads = jax.vmap(per_worker_grads)(state.params, batch)
+        losses, grads = worker_grads(state.params, batch)
         metrics = {"loss": jnp.mean(losses)}
 
         if not plan.is_parameter_sync:

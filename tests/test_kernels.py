@@ -1,5 +1,7 @@
 """Pallas kernel sweeps (interpret mode) vs pure-jnp oracles."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,19 +20,45 @@ from repro.kernels.ssd_scan import ssd_chunk, ssd_chunk_ref
 # flash attention
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("b,sq,nq,nkv,hd", [
+FLASH_CASES = [
     (1, 128, 4, 2, 32),
     (2, 192, 8, 8, 16),     # MHA
     (1, 256, 4, 1, 64),     # MQA
     (2, 100, 6, 2, 8),      # ragged seq (padding path)
-])
+    (1, 256, 32, 8, 64),    # granite-3-2b's head layout
+    (1, 200, 32, 8, 64),    # the same, ragged
+]
+
+
+def _qkv(b, sq, nq, nkv, hd, dtype, lead=()):
+    k0, k1, k2 = jax.random.split(jax.random.PRNGKey(sq + nq), 3)
+    return (jax.random.normal(k0, (*lead, b, sq, nq, hd), dtype),
+            jax.random.normal(k1, (*lead, b, sq, nkv, hd), dtype),
+            jax.random.normal(k2, (*lead, b, sq, nkv, hd), dtype))
+
+
+def _grads(attn, q, k, v):
+    """dq, dk, dv of a fixed random projection of ``attn``'s output."""
+    ct = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.float32)
+
+    def f(q, k, v):
+        return jnp.sum(attn(q, k, v).astype(jnp.float32) * ct)
+
+    return jax.jit(jax.grad(f, argnums=(0, 1, 2)))(q, k, v)
+
+
+def _close(got, want, tol):
+    """Agreement to ``tol`` of the reference's largest magnitude."""
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    scale = max(1.0, float(np.abs(want).max()))
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol * scale)
+
+
+@pytest.mark.parametrize("b,sq,nq,nkv,hd", FLASH_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention_sweep(b, sq, nq, nkv, hd, dtype):
-    k0, k1, k2 = jax.random.split(jax.random.PRNGKey(sq + nq), 3)
-    q = jax.random.normal(k0, (b, sq, nq, hd), dtype)
-    k = jax.random.normal(k1, (b, sq, nkv, hd), dtype)
-    v = jax.random.normal(k2, (b, sq, nkv, hd), dtype)
-    out = flash_attention(q, k, v, block_q=64, block_k=64)
+    q, k, v = _qkv(b, sq, nq, nkv, hd, dtype)
+    out = flash_attention(q, k, v)
     ref = attention_ref(q, k, v)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -38,13 +66,50 @@ def test_flash_attention_sweep(b, sq, nq, nkv, hd, dtype):
                                rtol=tol, atol=tol)
 
 
+@pytest.mark.parametrize("b,sq,nq,nkv,hd", FLASH_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_grad_sweep(b, sq, nq, nkv, hd, dtype):
+    """dq, dk and dv of the kernels' backward against the oracle's."""
+    q, k, v = _qkv(b, sq, nq, nkv, hd, dtype)
+    got = _grads(flash_attention, q, k, v)
+    want = _grads(attention_ref, q, k, v)
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    for name, g, w in zip("qkv", got, want, strict=True):
+        assert g.dtype == dtype, name
+        _close(g, w, tol)
+
+
+def test_flash_attention_under_worker_vmap():
+    """Forward and gradients vmapped over a leading worker axis, as the
+    DreamDDP step runs them, agree with the oracle worker by worker."""
+    q, k, v = _qkv(1, 128, 8, 2, 64, jnp.float32, lead=(3,))
+    out = jax.vmap(flash_attention)(q, k, v)
+    _close(out, jax.vmap(attention_ref)(q, k, v), 2e-5)
+    got = _grads(jax.vmap(flash_attention), q, k, v)
+    want = _grads(jax.vmap(attention_ref), q, k, v)
+    for g, w in zip(got, want, strict=True):
+        _close(g, w, 2e-5)
+
+
 def test_flash_attention_non_causal():
     k0 = jax.random.PRNGKey(0)
     q = jax.random.normal(k0, (1, 128, 2, 16))
-    out = flash_attention(q, q, q, causal=False, block_q=64, block_k=64)
+    out = flash_attention(q, q, q, causal=False)
     ref = attention_ref(q, q, q, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+def test_flash_attention_non_causal_ragged_hides_padding():
+    """A ragged sequence without the causal mask: the padded keys stay
+    out of every real query's softmax, forward and backward."""
+    q, k, v = _qkv(2, 100, 4, 2, 32, jnp.float32)
+    full = functools.partial(flash_attention, causal=False)
+    full_ref = functools.partial(attention_ref, causal=False)
+    _close(full(q, k, v), full_ref(q, k, v), 2e-5)
+    for g, w in zip(_grads(full, q, k, v), _grads(full_ref, q, k, v),
+                    strict=True):
+        _close(g, w, 2e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -134,3 +134,97 @@ def test_segment_cuts_preserve_function():
     a = model.apply(params, toks)
     b = model.apply(params, toks, segment_cuts=(2, 3))
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# Causal self-attention: the flash kernel's path and what stays on einsum
+# ---------------------------------------------------------------------------
+
+def _granite_f32(**over):
+    """granite-3-2b's smoke model in float32 (so two attention paths
+    can be compared tightly), with ``over`` applied to its config."""
+    from dataclasses import replace
+
+    from repro.models.transformer import DecoderLM
+    cfg = get_arch("granite-3-2b").make_smoke().cfg
+    return DecoderLM(replace(cfg, param_dtype="float32", **over))
+
+
+def test_flash_path_loss_and_grads_match_einsum(monkeypatch):
+    """The smoke model's loss and every gradient on the flash kernels'
+    path (interpret mode here, steered onto it as a TPU lowering would
+    be) agree with the einsum path the CPU takes."""
+    from repro.kernels.flash_attention import flash_attention
+    from repro.models import transformer
+
+    def loss_and_grads():
+        model = _granite_f32()          # a fresh model: no cached trace
+        params = model.init(jax.random.PRNGKey(0))
+        batch = _smoke_batch(get_arch("granite-3-2b"), model,
+                             jax.random.PRNGKey(1), b=2, s=40)
+        return jax.jit(jax.value_and_grad(model.loss))(params, batch)
+
+    loss, grads = loss_and_grads()
+    calls = []
+
+    def flash(q, k, v, positions):
+        calls.append(q.shape)
+        return flash_attention(q, k, v)
+
+    monkeypatch.setattr(transformer, "causal_self_attention", flash)
+    flash_loss, flash_grads = loss_and_grads()
+    assert calls
+    np.testing.assert_allclose(float(flash_loss), float(loss), rtol=1e-5)
+    flat = jax.tree_util.tree_leaves_with_path(grads)
+    for (path, want), got in zip(flat, jax.tree.leaves(flash_grads),
+                                 strict=True):
+        scale = max(1e-6, float(np.abs(want).max()))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-3, atol=1e-4 * scale,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+def _count_causal_calls(monkeypatch):
+    """Count the model's calls of ``causal_self_attention``."""
+    from repro.models import transformer
+    calls = []
+    real = transformer.causal_self_attention
+
+    def spy(q, k, v, positions):
+        calls.append(q.shape)
+        return real(q, k, v, positions)
+
+    monkeypatch.setattr(transformer, "causal_self_attention", spy)
+    return calls
+
+
+def test_attention_routing(monkeypatch):
+    """Training's loss and ``apply`` without positions take causal
+    self-attention, which carries the flash kernels for a TPU lowering;
+    explicit positions, a cache (prefill, decode) and a local window
+    keep the einsum path.  Each call gets a fresh model, so no cached
+    trace of a layer hides a call."""
+    calls = _count_causal_calls(monkeypatch)
+    params = _granite_f32().init(jax.random.PRNGKey(0))
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
+                              _granite_f32().cfg.vocab)
+    batch = {"tokens": toks, "labels": toks}
+
+    jaxpr = str(jax.make_jaxpr(jax.grad(_granite_f32().loss))(params,
+                                                              batch))
+    assert calls and "splash_mqa_fwd" in jaxpr and "splash_mqa_dkv" in jaxpr
+    calls.clear()
+    jax.make_jaxpr(_granite_f32().apply)(params, toks)
+    assert calls
+    calls.clear()
+
+    positions = jnp.broadcast_to(jnp.arange(16), (2, 16))
+    jax.make_jaxpr(lambda p, t: _granite_f32().apply(
+        p, t, positions=positions))(params, toks)
+    model = _granite_f32()
+    _, cache = jax.jit(model.prefill)(params, toks, model.init_cache(2, 32))
+    jax.jit(model.decode_step)(params, cache, toks[:, :1],
+                               jnp.full((2,), 16, jnp.int32))
+    jaxpr = str(jax.make_jaxpr(jax.grad(_granite_f32(window=8).loss))(
+        params, batch))
+    assert not calls and "pallas_call" not in jaxpr

@@ -12,12 +12,15 @@ the same tests.  The persistent compilation cache is off around these
 compiles (an entry written for a described chip cannot be read back).
 """
 
+import re
 from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.api import JobConfig, Session
 from repro.configs import get_arch
@@ -95,11 +98,10 @@ def _paged_args(one_chip, arch):
             _on_chip(one_chip, (slots,), jnp.int32))
 
 
-def test_granite_phase_step_fits_v5e(one_chip):
-    """One granite-3-2b DreamDDP phase step at published widths (one
-    layer, W=2, one 1024-token sequence per worker) compiles for the
-    chip, donating its state, within one chip's HBM."""
-    workers, seq = 2, 1024
+def _granite_step(workers, seq, place):
+    """A granite-3-2b DreamDDP phase step at published widths, one layer,
+    one ``seq``-token row per worker, and its state and batch as shapes
+    that ``place`` gives a sharding."""
     model = DecoderLM(replace(GRANITE, n_layers=1))
     sess = Session(JobConfig(arch="granite-3-2b", smoke=False,
                              workers=workers, period=2, seq=seq,
@@ -108,15 +110,68 @@ def test_granite_phase_step_fits_v5e(one_chip):
     opt = make_optimizer("adam")
     state = jax.eval_shape(lambda: init_train_state(
         model, opt, jax.random.PRNGKey(0), workers, cfg=scfg))
-    state = jax.tree.map(lambda s: _on_chip(one_chip, s.shape, s.dtype),
-                         state)
-    batch = {k: _on_chip(one_chip, (workers, 1, seq), jnp.int32)
+    state = jax.tree.map(lambda s: place(s.shape, s.dtype), state)
+    batch = {k: place((workers, 1, seq), jnp.int32)
              for k in ("tokens", "labels")}
     phase = next(h for h in range(plan.H) if plan.units_for_phase(h))
     step = jax.jit(make_train_step(model, opt, plan, phase, cfg=scfg),
                    donate_argnums=0)
+    return step, state, batch
+
+
+def test_granite_phase_step_fits_v5e(one_chip):
+    """One granite-3-2b DreamDDP phase step at published widths (one
+    layer, W=2, one 1024-token sequence per worker) compiles for the
+    chip, donating its state, within one chip's HBM."""
+    step, state, batch = _granite_step(
+        2, 1024, lambda shape, dtype: _on_chip(one_chip, shape, dtype))
     mem = step.lower(state, batch).compile().memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert mem.alias_size_in_bytes > 0          # the state is donated
     assert total < HBM_BYTES, total
+
+
+def _flash_kernels(hlo: str) -> list[str]:
+    """Names of the flash-attention kernels the program runs: Mosaic
+    custom calls under the ``repro_flash_attention`` scope.  An
+    instruction's text may run over several lines."""
+    return sorted(
+        re.match(r"\s*%(splash_\w+?)(\.\d+)? =", instr).group(1)
+        for instr in re.split(r"\n(?=\s+(?:ROOT )?%)", hlo)
+        if 'custom_call_target="tpu_custom_call"' in instr
+        and "repro_flash_attention" in instr)
+
+
+def test_granite_phase_step_runs_flash_kernels(one_chip):
+    """At the benchmark cell's widths and sequence (one layer, W=1, seq
+    4096) the phase step's attention is the flash kernels: the forward,
+    its recompute under the layer's remat, and the backward (dq and dkv
+    in one kernel).  No float32 score map (``[..., 1024, 4096]``, one
+    query chunk's) is left."""
+    step, state, batch = _granite_step(
+        1, 4096, lambda shape, dtype: _on_chip(one_chip, shape, dtype))
+    hlo = step.lower(state, batch).compile().as_text()
+    assert _flash_kernels(hlo) == [
+        "splash_mqa_dkv_no_residuals", "splash_mqa_fwd_residuals",
+        "splash_mqa_fwd_residuals"]
+    assert not re.search(r"f32\[[\d,]*1024,4096\]", hlo)
+
+
+def test_granite_phase_step_compiles_over_four_chips(topo, monkeypatch):
+    """W=4 with the worker axis spread over a described 2x2 mesh: the
+    step runs each chip's worker under ``shard_map`` (XLA cannot
+    partition a Mosaic kernel), so it compiles with the kernels in it
+    and no collective outside the phase's parameter sync."""
+    monkeypatch.setattr(jax, "devices", lambda *_, **__: list(topo.devices))
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+
+    def place(shape, dtype):
+        spec = P("data") if shape else P()
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    step, state, batch = _granite_step(4, 1024, place)
+    hlo = step.lower(state, batch).compile().as_text()
+    assert len(_flash_kernels(hlo)) == 3
+    assert not re.search(r"all-gather|all-to-all|collective-permute", hlo)
