@@ -2,15 +2,17 @@
 
 from __future__ import annotations
 
-from . import (deepseek_v3_671b, granite_3_2b, llava_next_34b, mamba2_780m,
-               phi4_mini_3_8b, qwen2_5_32b, qwen3_1_7b, qwen3_moe_30b_a3b,
-               recurrentgemma_9b, whisper_medium)
+from . import (deepseek_v3_671b, granite_3_2b, granite_4_0_h_micro,
+               llava_next_34b, mamba2_780m, phi4_mini_3_8b, qwen2_5_32b,
+               qwen3_1_7b, qwen3_moe_30b_a3b, recurrentgemma_9b,
+               whisper_medium)
 from .common import ArchSpec, batch_specs
 from .shapes import SHAPES, ShapeSpec
 
 _MODULES = (granite_3_2b, phi4_mini_3_8b, qwen2_5_32b, qwen3_1_7b,
             llava_next_34b, mamba2_780m, recurrentgemma_9b,
-            qwen3_moe_30b_a3b, deepseek_v3_671b, whisper_medium)
+            qwen3_moe_30b_a3b, deepseek_v3_671b, whisper_medium,
+            granite_4_0_h_micro)
 
 ARCHS: dict[str, ArchSpec] = {m.ARCH.arch_id: m.ARCH for m in _MODULES}
 

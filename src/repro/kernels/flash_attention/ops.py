@@ -57,12 +57,13 @@ def _splash(q, k, v, segment_ids, *, causal: bool, interpret: bool):
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True) -> jax.Array:
+                    causal: bool = True,
+                    scale: float | None = None) -> jax.Array:
     """Self-attention over one sequence of positions ``0 .. s-1``.
 
     q ``[b, s, n_q, hd]``; k, v ``[b, s, n_kv, hd]`` with ``n_kv``
-    dividing ``n_q`` -> ``[b, s, n_q, hd]`` in q's dtype.
-    Differentiable.  A sequence that is not a whole number of blocks is
+    dividing ``n_q`` -> ``[b, s, n_q, hd]`` in q's dtype.  Scores are
+    scaled by ``scale`` (``hd ** -0.5`` when None).  Differentiable.  A sequence that is not a whole number of blocks is
     padded at its end; a padded key is hidden from every real query (by
     causality, or by a segment of its own when ``causal`` is False).
     """
@@ -75,7 +76,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     sp = -(-s // block) * block
 
     with jax.named_scope("repro_flash_attention"):
-        q = (q * (hd ** -0.5)).astype(q.dtype)
+        q = (q * (hd ** -0.5 if scale is None else scale)).astype(q.dtype)
         q = q.reshape(b, s, n_kv, g, hd).transpose(0, 2, 3, 1, 4)
         k, v = (x.transpose(0, 2, 1, 3) for x in (k, v))
         if sp != s:

@@ -26,10 +26,10 @@ def _on_tpu() -> bool:
 
 
 @functools.partial(jax.jit, static_argnames=("window", "impl",
-                                             "skip_pages"))
+                                             "skip_pages", "scale"))
 def paged_attention(q, k_pages, v_pages, block_tables, kv_len, *,
                     window: int | None = None, impl: str | None = None,
-                    skip_pages: bool = True):
+                    skip_pages: bool = True, scale: float | None = None):
     """Paged-KV single-token decode attention.
 
     q ``[slots, n_q, hd]``, k/v pages ``[n_pages, page_size, n_kv, hd]``,
@@ -39,15 +39,16 @@ def paged_attention(q, k_pages, v_pages, block_tables, kv_len, *,
     tests), or ``"ref"``.  ``skip_pages`` (kernel impls only) stops each
     slot's page loop at ``ceil(kv_len / page_size)`` pages — bitwise-
     equal output, less page traffic; the ref path always gathers exactly
-    the table's pages.
+    the table's pages.  Scores are scaled by ``scale`` (``hd ** -0.5``
+    when None).
     """
     if impl is None:
         impl = "pallas" if _on_tpu() else "ref"
     if impl == "ref":
         return paged_attention_ref(q, k_pages, v_pages, block_tables,
-                                   kv_len, window=window)
+                                   kv_len, window=window, scale=scale)
     if impl not in ("pallas", "interpret"):
         raise ValueError(f"unknown paged_attention impl {impl!r}")
     return paged_attention_fwd(q, k_pages, v_pages, block_tables, kv_len,
                                window=window, skip_pages=skip_pages,
-                               interpret=impl == "interpret")
+                               interpret=impl == "interpret", scale=scale)

@@ -221,21 +221,22 @@ def gqa_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def causal_self_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                          positions: jax.Array) -> jax.Array:
+                          positions: jax.Array, *,
+                          scale: float | None = None) -> jax.Array:
     """Causal GQA self-attention of a sequence over its own positions:
-    ``positions [b, s]`` must be ``0 .. s-1`` in every row (shapes as
-    :func:`gqa_attention`).
+    ``positions [b, s]`` must be ``0 .. s-1`` in every row (shapes and
+    ``scale`` as :func:`gqa_attention`).
 
     Where the program lowers for TPU this is the flash kernel, forward
     and backward, which needs no positions; elsewhere it is
     :func:`gqa_attention` over ``positions``.  The branch is chosen at
     lowering, so a compile for a described TPU takes the kernel too."""
     def flash(q, k, v, _):
-        return flash_attention(q, k, v)
+        return flash_attention(q, k, v, scale=scale)
 
     def einsum(q, k, v, positions):
         return gqa_attention(q, k, v, q_positions=positions,
-                             kv_positions=positions)
+                             kv_positions=positions, scale=scale)
 
     return jax.lax.platform_dependent(q, k, v, positions, tpu=flash,
                                       default=einsum)

@@ -9,7 +9,10 @@ sequence length).
 Layout follows the reference implementation: ``in_proj`` emits
 ``[z, x, B, C, dt]``; a causal depthwise conv (width 4) runs over
 ``[x, B, C]``; the SSD core uses per-head scalar decay ``A``; output is
-gated-RMSNormed and projected back.
+gated-RMSNormed and projected back.  That mixer
+(:func:`mamba2_mixer_init` / :func:`mamba2_mixer_apply`) is shared with
+the hybrid :class:`~repro.models.transformer.DecoderLM`, where an MLP
+follows it in every layer.
 
 A Pallas kernel for the chunk-local core lives in
 ``repro.kernels.ssd_scan`` (this module is its ``ref`` semantics).
@@ -26,26 +29,26 @@ import jax.numpy as jnp
 from ..core.partial_sync import UnitEntry, UnitLayout
 from .layers import Init, dense, norm_init, rms_norm, softmax_xent
 
-__all__ = ["Mamba2Config", "Mamba2LM", "ssd_chunked", "ssd_decode_step"]
+__all__ = ["MixerConfig", "Mamba2Config", "Mamba2LM", "ssd_chunked",
+           "ssd_decode_step", "mamba2_mixer_init", "mamba2_mixer_apply",
+           "mamba2_mixer_param_count", "mamba2_mixer_fwd_flops"]
 
 PyTree = Any
 
 
 @dataclass(frozen=True)
-class Mamba2Config:
-    name: str
-    n_layers: int
+class MixerConfig:
+    """Widths of one Mamba-2 mixer (``in_proj`` -> causal conv -> SSD ->
+    gated RMSNorm -> ``out_proj``)."""
+
     d_model: int
-    vocab: int
     d_state: int = 128
     head_dim: int = 64
     expand: int = 2
     n_groups: int = 1
     conv_width: int = 4
     chunk: int = 128
-    param_dtype: str = "float32"
-    remat: bool = True
-    tie_embeddings: bool = True
+    norm_eps: float = 1e-6              # the gated RMSNorm's epsilon
 
     @property
     def d_inner(self) -> int:
@@ -63,6 +66,30 @@ class Mamba2Config:
     def d_in_proj(self) -> int:
         return 2 * self.d_inner + 2 * self.n_groups * self.d_state \
             + self.n_heads
+
+
+@dataclass(frozen=True)
+class Mamba2Config:
+    name: str
+    n_layers: int
+    d_model: int
+    vocab: int
+    d_state: int = 128
+    head_dim: int = 64
+    expand: int = 2
+    n_groups: int = 1
+    conv_width: int = 4
+    chunk: int = 128
+    param_dtype: str = "float32"
+    remat: bool = True
+    tie_embeddings: bool = True
+    norm_eps: float = 1e-6
+
+    @property
+    def mixer(self) -> MixerConfig:
+        return MixerConfig(self.d_model, self.d_state, self.head_dim,
+                           self.expand, self.n_groups, self.conv_width,
+                           self.chunk, self.norm_eps)
 
     @property
     def dtype(self):
@@ -164,6 +191,132 @@ def ssd_decode_step(x: jax.Array, dt: jax.Array, a_log: jax.Array,
 
 
 # ---------------------------------------------------------------------------
+# The mixer (shared by Mamba2LM and the hybrid DecoderLM)
+# ---------------------------------------------------------------------------
+
+def mamba2_mixer_init(init: Init, mc: MixerConfig, dtype):
+    """One mixer's parameters and their logical-axis spec.  ``a_log``,
+    ``dt_bias`` and the skip ``d_skip`` are float32 whatever ``dtype``."""
+    p = {
+        "in_proj": {"w": init.normal((mc.d_model, mc.d_in_proj),
+                                     mc.d_model ** -0.5, dtype)},
+        "conv": init.normal((mc.conv_width, mc.conv_dim),
+                            mc.conv_width ** -0.5, dtype),
+        "conv_bias": jnp.zeros((mc.conv_dim,), dtype),
+        "a_log": jnp.log(jnp.linspace(1.0, 16.0, mc.n_heads,
+                                      dtype=jnp.float32)),
+        "dt_bias": jnp.zeros((mc.n_heads,), jnp.float32),
+        "d_skip": jnp.ones((mc.n_heads,), jnp.float32),
+        "out_norm": norm_init(mc.d_inner, dtype=dtype)[0],
+        "out_proj": {"w": init.normal((mc.d_inner, mc.d_model),
+                                      mc.d_inner ** -0.5, dtype)},
+    }
+    spec = {
+        "in_proj": {"w": (None, "heads")},
+        "conv": (None, "heads"),
+        "conv_bias": ("heads",),
+        "a_log": ("heads",),
+        "dt_bias": ("heads",),
+        "d_skip": ("heads",),
+        "out_norm": {"scale": ("heads",)},
+        "out_proj": {"w": ("heads", None)},
+    }
+    return p, spec
+
+
+def _causal_conv(w: jax.Array, bias: jax.Array, u: jax.Array) -> jax.Array:
+    """Causal depthwise conv over time, then SiLU.  u ``[B, L, C]``,
+    w ``[W, C]``; sums in float32, result in ``u``'s dtype."""
+    width = w.shape[0]
+    pad = jnp.pad(u, ((0, 0), (width - 1, 0), (0, 0)))
+    out = sum(pad[:, i:i + u.shape[1]].astype(jnp.float32)
+              * w[i].astype(jnp.float32) for i in range(width))
+    return jax.nn.silu(out + bias.astype(jnp.float32)).astype(u.dtype)
+
+
+def _gated_norm(scale: jax.Array, y: jax.Array, z: jax.Array, groups: int,
+                eps: float, dtype) -> jax.Array:
+    """``rmsnorm(y * silu(z))`` over each of ``groups`` equal slices of
+    the last axis, in float32; the normed tensor in ``dtype`` times the
+    scale."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    if groups > 1:
+        g = g.reshape(g.shape[:-1] + (groups, -1))
+    ms = jnp.mean(jnp.square(g), axis=-1, keepdims=True)
+    g = (g * jax.lax.rsqrt(ms + eps)).reshape(y.shape)
+    return g.astype(dtype) * scale
+
+
+def mamba2_mixer_apply(p, mc: MixerConfig, x: jax.Array, conv_state=None,
+                       ssm_state=None):
+    """One Mamba-2 mixer over ``x [B, L, d]``.
+
+    Returns ``(y [B, L, d], conv_state, ssm_state)`` in ``x``'s dtype.
+    Without states it runs the whole sequence (training, prefill) and
+    returns the states the sequence leaves; with them it is the O(1)
+    decode step (``L == 1``).  Runs under the named scope
+    ``mamba_mixer``, its SSD core under ``ssd``."""
+    b, l, _ = x.shape
+    with jax.named_scope("mamba_mixer"):
+        z, xc, bmat, cmat, dt = jnp.split(
+            dense(p["in_proj"], x),
+            [mc.d_inner, 2 * mc.d_inner,
+             2 * mc.d_inner + mc.n_groups * mc.d_state,
+             2 * mc.d_inner + 2 * mc.n_groups * mc.d_state], axis=-1)
+        conv_in = jnp.concatenate([xc, bmat, cmat], -1)
+        if conv_state is None:
+            conv_out = _causal_conv(p["conv"], p["conv_bias"], conv_in)
+            new_conv_state = conv_in[:, -(mc.conv_width - 1):]
+        else:
+            # roll the conv window: state [B, W-1, C]
+            hist = jnp.concatenate([conv_state, conv_in], 1)
+            new_conv_state = hist[:, 1:]
+            conv_out = jax.nn.silu(
+                jnp.einsum("bwc,wc->bc", hist, p["conv"])
+                + p["conv_bias"])[:, None]
+
+        xq, bq, cq = jnp.split(
+            conv_out, [mc.d_inner, mc.d_inner + mc.n_groups * mc.d_state],
+            axis=-1)
+        xq = xq.reshape(b, l, mc.n_heads, mc.head_dim)
+        bq = bq.reshape(b, l, mc.n_groups, mc.d_state)
+        cq = cq.reshape(b, l, mc.n_groups, mc.d_state)
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
+
+        with jax.named_scope("ssd"):
+            if ssm_state is None:
+                y, final = ssd_chunked(xq, dt, p["a_log"], bq, cq, mc.chunk)
+            else:
+                y1, final = ssd_decode_step(xq[:, 0], dt[:, 0], p["a_log"],
+                                            bq[:, 0], cq[:, 0], ssm_state)
+                y = y1[:, None]
+        y = y + xq * p["d_skip"][:, None].astype(y.dtype)
+        y = _gated_norm(p["out_norm"]["scale"], y.reshape(b, l, mc.d_inner),
+                        z, mc.n_groups, mc.norm_eps, x.dtype)
+        return dense(p["out_proj"], y), new_conv_state, final
+
+
+def mamba2_mixer_param_count(mc: MixerConfig) -> int:
+    return (mc.d_model * mc.d_in_proj                       # in_proj
+            + mc.conv_width * mc.conv_dim + mc.conv_dim     # conv
+            + 3 * mc.n_heads                                # a/dt/D
+            + mc.d_inner                                    # out_norm
+            + mc.d_inner * mc.d_model)                      # out_proj
+
+
+def mamba2_mixer_fwd_flops(mc: MixerConfig, tokens: int, *,
+                           decode: bool = False) -> float:
+    """Forward FLOPs of one mixer over ``tokens`` tokens: projections and
+    the chunked SSD (or the recurrent step when ``decode``)."""
+    proj = 2.0 * tokens * mc.d_model * (mc.d_in_proj + mc.d_inner)
+    state = 4.0 * tokens * mc.n_heads * mc.head_dim * mc.d_state
+    if decode:
+        return proj + state
+    return proj + state + 2.0 * tokens * mc.chunk * mc.n_heads * (
+        mc.d_state + mc.head_dim)
+
+
+# ---------------------------------------------------------------------------
 # Model
 # ---------------------------------------------------------------------------
 
@@ -174,38 +327,14 @@ class Mamba2LM:
 
     def __init__(self, cfg: Mamba2Config):
         self.cfg = cfg
+        self.mix = cfg.mixer
 
     # ------------------------------------------------------------------ init
     def _block_init(self, key: jax.Array):
         cfg = self.cfg
-        init = Init(key)
-        d = cfg.d_model
-        p = {
-            "ln": norm_init(d, dtype=cfg.dtype)[0],
-            "in_proj": {"w": init.normal((d, cfg.d_in_proj), d ** -0.5,
-                                         cfg.dtype)},
-            "conv": init.normal((cfg.conv_width, cfg.conv_dim),
-                                cfg.conv_width ** -0.5, cfg.dtype),
-            "conv_bias": jnp.zeros((cfg.conv_dim,), cfg.dtype),
-            "a_log": jnp.log(jnp.linspace(1.0, 16.0, cfg.n_heads,
-                                          dtype=jnp.float32)),
-            "dt_bias": jnp.zeros((cfg.n_heads,), jnp.float32),
-            "d_skip": jnp.ones((cfg.n_heads,), jnp.float32),
-            "out_norm": norm_init(cfg.d_inner, dtype=cfg.dtype)[0],
-            "out_proj": {"w": init.normal((cfg.d_inner, d),
-                                          cfg.d_inner ** -0.5, cfg.dtype)},
-        }
-        spec = {
-            "ln": {"scale": (None,)},
-            "in_proj": {"w": (None, "heads")},
-            "conv": (None, "heads"),
-            "conv_bias": ("heads",),
-            "a_log": ("heads",),
-            "dt_bias": ("heads",),
-            "d_skip": ("heads",),
-            "out_norm": {"scale": ("heads",)},
-            "out_proj": {"w": ("heads", None)},
-        }
+        mixer, mixer_spec = mamba2_mixer_init(Init(key), self.mix, cfg.dtype)
+        p = {"ln": norm_init(cfg.d_model, dtype=cfg.dtype)[0], **mixer}
+        spec = {"ln": {"scale": (None,)}, **mixer_spec}
         return p, spec
 
     def init(self, key: jax.Array) -> PyTree:
@@ -243,66 +372,9 @@ class Mamba2LM:
         return specs
 
     # ----------------------------------------------------------------- apply
-    def _split_proj(self, zxbcdt: jax.Array):
-        cfg = self.cfg
-        return jnp.split(
-            zxbcdt,
-            [cfg.d_inner, 2 * cfg.d_inner,
-             2 * cfg.d_inner + cfg.n_groups * cfg.d_state,
-             2 * cfg.d_inner + 2 * cfg.n_groups * cfg.d_state],
-            axis=-1)
-
-    def _conv_full(self, p, u: jax.Array) -> jax.Array:
-        """Causal depthwise conv over time.  u ``[B, L, C]``."""
-        w = p["conv"]                                  # [W, C]
-        width = w.shape[0]
-        pad = jnp.pad(u, ((0, 0), (width - 1, 0), (0, 0)))
-        out = sum(pad[:, i:i + u.shape[1]] * w[i] for i in range(width))
-        return jax.nn.silu(out + p["conv_bias"])
-
-    def _block_core(self, p, x: jax.Array, conv_state=None, ssm_state=None):
-        """Returns (y, new_conv_state, new_ssm_state).  Full-seq when states
-        are None (train/prefill), O(1) step when given (decode, L == 1)."""
-        cfg = self.cfg
-        b, l, _ = x.shape
-        z, xc, bmat, cmat, dt = self._split_proj(dense(p["in_proj"], x))
-        conv_in = jnp.concatenate([xc, bmat, cmat], -1)
-
-        if conv_state is None:
-            conv_out = self._conv_full(p, conv_in)
-            new_conv_state = None
-            if False:
-                pass  # ssd_chunked pads internally
-        else:
-            # roll the conv window: state [B, W-1, C]
-            hist = jnp.concatenate([conv_state, conv_in], 1)
-            new_conv_state = hist[:, 1:]
-            w = p["conv"]
-            conv_out = jax.nn.silu(
-                jnp.einsum("bwc,wc->bc", hist, w) + p["conv_bias"])[:, None]
-
-        xq, bq, cq = jnp.split(
-            conv_out, [cfg.d_inner, cfg.d_inner + cfg.n_groups * cfg.d_state],
-            axis=-1)
-        xq = xq.reshape(b, l, cfg.n_heads, cfg.head_dim)
-        bq = bq.reshape(b, l, cfg.n_groups, cfg.d_state)
-        cq = cq.reshape(b, l, cfg.n_groups, cfg.d_state)
-        dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
-
-        if ssm_state is None:
-            y, final = ssd_chunked(xq, dt, p["a_log"], bq, cq, cfg.chunk)
-        else:
-            y1, final = ssd_decode_step(xq[:, 0], dt[:, 0], p["a_log"],
-                                        bq[:, 0], cq[:, 0], ssm_state)
-            y = y1[:, None]
-        y = y + xq * p["d_skip"][:, None].astype(y.dtype)
-        y = y.reshape(b, l, cfg.d_inner)
-        y = rms_norm(p["out_norm"], y * jax.nn.silu(z))
-        return dense(p["out_proj"], y), new_conv_state, final
-
     def _block_apply(self, p, x, conv_state=None, ssm_state=None):
-        y, ncs, nss = self._block_core(p, rms_norm(p["ln"], x),
-                                       conv_state, ssm_state)
+        y, ncs, nss = mamba2_mixer_apply(p, self.mix, rms_norm(p["ln"], x),
+                                         conv_state, ssm_state)
         return x + y.astype(x.dtype), ncs, nss
 
     def _backbone(self, params, tokens, cache=None):
@@ -342,10 +414,10 @@ class Mamba2LM:
 
     # --------------------------------------------------------------- serving
     def init_cache(self, batch: int, max_seq: int) -> PyTree:
-        cfg = self.cfg
+        cfg, mc = self.cfg, self.mix
         one = (
-            jnp.zeros((batch, cfg.conv_width - 1, cfg.conv_dim), cfg.dtype),
-            jnp.zeros((batch, cfg.n_heads, cfg.head_dim, cfg.d_state),
+            jnp.zeros((batch, mc.conv_width - 1, mc.conv_dim), cfg.dtype),
+            jnp.zeros((batch, mc.n_heads, mc.head_dim, mc.d_state),
                       jnp.float32),
         )
         return jax.tree.map(
@@ -359,28 +431,8 @@ class Mamba2LM:
 
         def body(carry, xs):
             lp, _ = xs
-            xin = rms_norm(lp["ln"], carry)
-            b, l, _ = xin.shape
-            z, xc, bmat, cmat, dt = self._split_proj(dense(lp["in_proj"],
-                                                           xin))
-            conv_in = jnp.concatenate([xc, bmat, cmat], -1)
-            conv_out = self._conv_full(lp, conv_in)
-            new_conv = conv_in[:, -(cfg.conv_width - 1):]
-            xq = conv_out[..., :cfg.d_inner].reshape(b, l, cfg.n_heads,
-                                                     cfg.head_dim)
-            bq = conv_out[..., cfg.d_inner:cfg.d_inner + cfg.n_groups
-                          * cfg.d_state].reshape(b, l, cfg.n_groups,
-                                                 cfg.d_state)
-            cq = conv_out[..., cfg.d_inner + cfg.n_groups
-                          * cfg.d_state:].reshape(b, l, cfg.n_groups,
-                                                  cfg.d_state)
-            dtp = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
-            y, final = ssd_chunked(xq, dtp, lp["a_log"], bq, cq, cfg.chunk)
-            y = y + xq * lp["d_skip"][:, None].astype(y.dtype)
-            y = y.reshape(b, l, cfg.d_inner)
-            y = rms_norm(lp["out_norm"], y * jax.nn.silu(z))
-            return carry + dense(lp["out_proj"], y).astype(carry.dtype), \
-                (new_conv.astype(cfg.dtype), final)
+            y, new_conv, final = self._block_apply(lp, carry)
+            return y, (new_conv.astype(cfg.dtype), final)
 
         x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache))
         return self._head(params, x[:, -1:]), new_cache
@@ -399,13 +451,7 @@ class Mamba2LM:
         return UnitLayout(tuple(entries))
 
     def _block_param_count(self) -> int:
-        cfg = self.cfg
-        return (cfg.d_model                                     # ln
-                + cfg.d_model * cfg.d_in_proj                   # in_proj
-                + cfg.conv_width * cfg.conv_dim + cfg.conv_dim  # conv
-                + 3 * cfg.n_heads                               # a/dt/D
-                + cfg.d_inner                                   # out_norm
-                + cfg.d_inner * cfg.d_model)                    # out_proj
+        return self.cfg.d_model + mamba2_mixer_param_count(self.mix)
 
     def param_count(self) -> int:
         cfg = self.cfg
@@ -424,15 +470,10 @@ class Mamba2LM:
         out = [("embed", float(cfg.vocab * cfg.d_model),
                 2.0 * tokens * cfg.d_model)]
         per_p = float(self._block_param_count())
-        proj = 2.0 * tokens * cfg.d_model * (cfg.d_in_proj + cfg.d_inner)
-        if mode == "train":
-            ssd = 2.0 * tokens * cfg.chunk * cfg.n_heads * (
-                cfg.d_state + cfg.head_dim) \
-                + 4.0 * tokens * cfg.n_heads * cfg.head_dim * cfg.d_state
-        else:
-            ssd = 4.0 * tokens * cfg.n_heads * cfg.head_dim * cfg.d_state
+        per_f = mamba2_mixer_fwd_flops(self.mix, tokens,
+                                       decode=mode != "train")
         for i in range(cfg.n_layers):
-            out.append((f"layer_{i}", per_p, proj + ssd))
+            out.append((f"layer_{i}", per_p, per_f))
         head_p = float(cfg.d_model + (0 if cfg.tie_embeddings
                                       else cfg.d_model * cfg.vocab))
         out.append(("head", head_p, 2.0 * tokens * cfg.d_model * cfg.vocab))

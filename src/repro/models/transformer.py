@@ -1,16 +1,19 @@
-"""Generic decoder-only LM: dense GQA, MoE, and MLA variants.
+"""Generic decoder-only LM: dense GQA, MoE, MLA and hybrid Mamba-2 variants.
 
-One class serves seven of the ten assigned architectures (granite, phi4,
-qwen2.5, qwen3, llava backbone, qwen3-moe, deepseek-v3).  Blocks of the same
-kind are **stacked** (leading layer axis) and executed with
-``jax.lax.scan`` — constant HLO size in depth, the standard TPU idiom — and
-the stack can be split at arbitrary unit boundaries (``segment_cuts``) so a
-DreamDDP phase's parameter all-reduce becomes data-independent of the
-remaining backward segments (the overlap window XLA's latency-hiding
-scheduler exploits; DESIGN.md §2).
+One class serves eight of the eleven assigned architectures (granite,
+granite-4.0-h, phi4, qwen2.5, qwen3, llava backbone, qwen3-moe,
+deepseek-v3).  Blocks of the same kind are **stacked** (leading layer axis)
+and executed with ``jax.lax.scan`` — constant HLO size in depth, the
+standard TPU idiom — and the stack can be split at arbitrary unit
+boundaries (``segment_cuts``) so a DreamDDP phase's parameter all-reduce
+becomes data-independent of the remaining backward segments (the overlap
+window XLA's latency-hiding scheduler exploits; DESIGN.md §2).  A hybrid
+(``LMConfig.layer_types``) keeps one stack per kind and runs slices of the
+stacks in the published layer order.
 
 Parameter tree = dict of *groups* (the partial-sync unit space):
-``embed`` / [``dense_blocks``] / ``blocks`` / [``mtp``] / ``head``.
+``embed`` / [``dense_blocks`` | ``mamba_blocks``] / ``blocks`` / [``mtp``]
+/ ``head``.
 """
 
 from __future__ import annotations
@@ -30,10 +33,15 @@ from .layers import (Init, apply_rope, causal_self_attention, dense,
                      dense_init, embed_init, gqa_attention, layer_norm,
                      mlp_apply, mlp_init, norm_init, rms_norm, rope_freqs,
                      softmax_xent)
+from .mamba2 import (MixerConfig, mamba2_mixer_apply, mamba2_mixer_fwd_flops,
+                     mamba2_mixer_init, mamba2_mixer_param_count)
 
 __all__ = ["LMConfig", "DecoderLM"]
 
 PyTree = Any
+
+# a hybrid's layer type -> (stacked group, block kind)
+_HYBRID = {"mamba": ("mamba_blocks", "mamba"), "attention": ("blocks", "dense")}
 
 
 @dataclass(frozen=True)
@@ -64,6 +72,31 @@ class LMConfig:
     # Multi-token prediction (dsv3)
     mtp: bool = False
     mtp_weight: float = 0.3
+    norm_eps: float = 1e-6                # RMSNorm epsilon
+    rope: bool = True                     # False: no positional encoding
+    # Granite's scales: token embeddings times, each residual branch
+    # times, logits divided by; attention scores times (None: hd ** -0.5)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: float | None = None
+    # hybrid: each layer's mixer in network order, "attention" (GQA) or
+    # "mamba" (a Mamba-2 mixer of widths ``mamba``); MLPs are dense
+    layer_types: tuple[str, ...] | None = None
+    mamba: MixerConfig | None = None
+
+    def __post_init__(self):
+        if self.layer_types is None:
+            return
+        if len(self.layer_types) != self.n_layers \
+                or not set(self.layer_types) <= set(_HYBRID):
+            raise ValueError(f"layer_types must give {self.n_layers} of "
+                             f"{sorted(_HYBRID)}: {self.layer_types}")
+        if "mamba" in self.layer_types and self.mamba is None:
+            raise ValueError("mamba layers need LMConfig.mamba")
+        if self.moe is not None or self.mla is not None or self.mtp:
+            raise ValueError("a hybrid's layers are GQA or Mamba-2 mixers "
+                             "with dense MLPs")
 
     @property
     def hd(self) -> int:
@@ -74,13 +107,33 @@ class LMConfig:
         return jnp.dtype(self.param_dtype)
 
     def runs(self) -> list[tuple[str, str, int]]:
-        """(group_name, block_kind, n_layers) in network order."""
+        """(group_name, block_kind, n_layers), one per stacked group, in
+        the order of each group's first layer."""
+        if self.layer_types is not None:
+            n = {(g, k): hi for g, k, _, hi in self.pieces()}
+            return [(g, k, hi) for (g, k), hi in n.items()]
         if self.moe is None:
             return [("blocks", "dense", self.n_layers)]
         out = []
         if self.n_dense_layers:
             out.append(("dense_blocks", "dense", self.n_dense_layers))
         out.append(("blocks", "moe", self.n_layers - self.n_dense_layers))
+        return out
+
+    def pieces(self) -> list[tuple[str, str, int, int]]:
+        """(group_name, block_kind, lo, hi) in network order: the layers
+        ``lo .. hi-1`` of a group's stack run next."""
+        if self.layer_types is None:
+            return [(g, k, 0, n) for g, k, n in self.runs()]
+        out: list[tuple[str, str, int, int]] = []
+        seen: dict[str, int] = {}
+        for t in self.layer_types:
+            g, k = _HYBRID[t]
+            i = seen[g] = seen.get(g, -1) + 1
+            if out and out[-1][0] == g:
+                out[-1] = (g, k, out[-1][2], i + 1)
+            else:
+                out.append((g, k, i, i + 1))
         return out
 
 
@@ -129,7 +182,11 @@ class DecoderLM:
         p, s = {}, {}
         p["ln1"], s["ln1"] = norm_init(cfg.d_model, dtype=cfg.dtype,
                                        bias=cfg.norm_kind == "layernorm")
-        p["attn"], s["attn"] = self._attn_init(init)
+        if kind == "mamba":
+            p["mixer"], s["mixer"] = mamba2_mixer_init(init, cfg.mamba,
+                                                       cfg.dtype)
+        else:
+            p["attn"], s["attn"] = self._attn_init(init)
         p["ln2"], s["ln2"] = norm_init(cfg.d_model, dtype=cfg.dtype,
                                        bias=cfg.norm_kind == "layernorm")
         if kind == "moe":
@@ -220,9 +277,10 @@ class DecoderLM:
         if cfg.qk_norm:
             q = rms_norm(p["q_norm"], q)
             k = rms_norm(p["k_norm"], k)
-        inv_freq = rope_freqs(hd, cfg.rope_theta)
-        q = apply_rope(q, positions, inv_freq)
-        k = apply_rope(k, positions, inv_freq)
+        if cfg.rope:
+            inv_freq = rope_freqs(hd, cfg.rope_theta)
+            q = apply_rope(q, positions, inv_freq)
+            k = apply_rope(k, positions, inv_freq)
         return q, k, v
 
     def _attend(self, p, x, positions, cache, write_pos,
@@ -255,13 +313,14 @@ class DecoderLM:
         b, s, _ = x.shape
         q, k, v = self._project_qkv(p, x, positions)
 
+        scale = cfg.attention_multiplier
         if cache is None and own_positions and cfg.window is None:
-            out = causal_self_attention(q, k, v, positions)
+            out = causal_self_attention(q, k, v, positions, scale=scale)
             new_cache = None
         elif cache is None:
             out = gqa_attention(q, k, v, q_positions=positions,
                                 kv_positions=positions, causal=True,
-                                window=cfg.window)
+                                window=cfg.window, scale=scale)
             new_cache = None
         else:
             pos0 = write_pos[0]
@@ -273,33 +332,46 @@ class DecoderLM:
             kv_pos = jnp.broadcast_to(jnp.arange(sk), (b, sk))
             out = gqa_attention(q, ck, cv, q_positions=positions,
                                 kv_positions=kv_pos, causal=True,
-                                window=cfg.window,
+                                window=cfg.window, scale=scale,
                                 kv_valid_len=write_pos + s)
             new_cache = {"k": ck, "v": cv}
         return out.reshape(b, s, -1) @ p["wo"]["w"], new_cache
 
     def _norm(self, p, x):
-        return (rms_norm(p, x) if self.cfg.norm_kind == "rmsnorm"
-                else layer_norm(p, x))
+        return (rms_norm(p, x, eps=self.cfg.norm_eps)
+                if self.cfg.norm_kind == "rmsnorm" else layer_norm(p, x))
+
+    def _add(self, x, branch):
+        """The residual stream plus a branch times the residual
+        multiplier."""
+        rm = self.cfg.residual_multiplier
+        return x + (branch if rm == 1.0 else branch * rm)
+
+    def _mlp(self, kind: str, p, x):
+        h = self._norm(p["ln2"], x)
+        if kind == "moe":
+            return self._add(x, moe_mod.moe_apply(p["mlp"], self.cfg.moe, h))
+        return self._add(x, mlp_apply(p["mlp"], h, kind=self.cfg.mlp_kind))
 
     def _block_apply(self, kind: str, p, x, positions, cache=None,
                      write_pos=None, own_positions=False):
-        a, new_cache = self._attend(p["attn"], self._norm(p["ln1"], x),
-                                    positions, cache, write_pos,
-                                    own_positions)
-        x = x + a
-        h = self._norm(p["ln2"], x)
-        if kind == "moe":
-            x = x + moe_mod.moe_apply(p["mlp"], self.cfg.moe, h)
+        h = self._norm(p["ln1"], x)
+        if kind == "mamba":
+            a, new_cache = mamba2_mixer_apply(p["mixer"], self.cfg.mamba,
+                                              h)[0], None
         else:
-            x = x + mlp_apply(p["mlp"], h, kind=self.cfg.mlp_kind)
-        return x, new_cache
+            a, new_cache = self._attend(p["attn"], h, positions, cache,
+                                        write_pos, own_positions)
+        return self._mlp(kind, p, self._add(x, a)), new_cache
 
     def _run_stack(self, kind, stacked, x, positions, cache=None,
-                   write_pos=None, cuts=(), own_positions=False):
-        """Scan a block stack over its layer axis, split at ``cuts``."""
-        n = jax.tree_util.tree_leaves(stacked)[0].shape[0]
-        bounds = sorted({0, n, *[c for c in cuts if 0 < c < n]})
+                   write_pos=None, cuts=(), own_positions=False,
+                   span=None):
+        """Scan a block stack's layers ``span`` (``(lo, hi)``, all of
+        them by default) over its layer axis, split at ``cuts``."""
+        lo0, hi0 = span or (0, jax.tree_util.tree_leaves(stacked)[0]
+                            .shape[0])
+        bounds = sorted({lo0, hi0, *[c for c in cuts if lo0 < c < hi0]})
         caches = []
         for lo, hi in zip(bounds[:-1], bounds[1:], strict=True):
             seg = jax.tree.map(lambda a, lo=lo, hi=hi: a[lo:hi], stacked)
@@ -330,15 +402,20 @@ class DecoderLM:
         if embeds is not None:
             parts.append(embeds.astype(cfg.dtype))
         if tokens is not None:
-            parts.append(params["embed"]["table"][tokens])
+            e = params["embed"]["table"][tokens]
+            m = cfg.embedding_multiplier
+            parts.append(e if m == 1.0 else e * m)
         return jnp.concatenate(parts, axis=1) if len(parts) > 1 \
             else parts[0]
 
     def _head(self, params, x):
         x = self._norm(params["head"]["norm"], x)
         if self.cfg.tie_embeddings:
-            return x @ params["embed"]["table"].T
-        return dense(params["head"]["out"], x)
+            logits = x @ params["embed"]["table"].T
+        else:
+            logits = dense(params["head"]["out"], x)
+        ls = self.cfg.logits_scaling
+        return logits if ls == 1.0 else logits / ls
 
     def _backbone(self, params, tokens=None, *, embeds=None, positions=None,
                   segment_cuts: tuple[int, ...] = ()) -> jax.Array:
@@ -356,12 +433,14 @@ class DecoderLM:
         if own_positions:
             positions = jnp.broadcast_to(jnp.arange(s), (b, s))
         unit0 = 1                        # unit 0 is the embedding
-        for group, kind, n in cfg.runs():
-            local_cuts = tuple(c - unit0 for c in segment_cuts
+        for group, kind, lo, hi in cfg.pieces():
+            n = hi - lo
+            local_cuts = tuple(lo + c - unit0 for c in segment_cuts
                                if unit0 < c < unit0 + n)
             x, _ = self._run_stack(kind, params[group], x, positions,
                                    cuts=local_cuts,
-                                   own_positions=own_positions)
+                                   own_positions=own_positions,
+                                   span=(lo, hi))
             unit0 += n
         return x
 
@@ -407,7 +486,14 @@ class DecoderLM:
         return softmax_xent(logits[:, :-1], labels[:, 2:])
 
     # --------------------------------------------------------------- serving
+    def _kv_only(self) -> None:
+        if self.cfg.mamba is not None:
+            raise NotImplementedError(
+                f"{self.cfg.name}: serving keeps attention KV only; a "
+                "hybrid's Mamba-2 state has no cache here")
+
     def init_cache(self, batch: int, max_seq: int) -> PyTree:
+        self._kv_only()
         cfg = self.cfg
         cache: dict = {}
         for group, _kind, n in cfg.runs():
@@ -463,6 +549,7 @@ class DecoderLM:
         page_size, ...]`` (GQA: k/v heads; MLA: latent + key-rope).  Page
         0 is reserved by the pool as a trash page (see
         :class:`repro.serve.cache.PagedCachePool`)."""
+        self._kv_only()
         cfg = self.cfg
         cache: dict = {}
         for group, _kind, n in cfg.runs():
@@ -498,7 +585,8 @@ class DecoderLM:
         cv = write_token_to_pages(pages["v"], block_tables, pos, active,
                                   v[:, 0])
         out = paged_attention(q[:, 0], ck, cv, block_tables, pos + 1,
-                              window=cfg.window)
+                              window=cfg.window,
+                              scale=cfg.attention_multiplier)
         return out.reshape(b, s, -1) @ p["wo"]["w"], {"k": ck, "v": cv}
 
     def _block_apply_paged(self, kind, p, x, positions, pages,
@@ -507,13 +595,7 @@ class DecoderLM:
                                           self._norm(p["ln1"], x),
                                           positions, pages, block_tables,
                                           pos, active)
-        x = x + a
-        h = self._norm(p["ln2"], x)
-        if kind == "moe":
-            x = x + moe_mod.moe_apply(p["mlp"], self.cfg.moe, h)
-        else:
-            x = x + mlp_apply(p["mlp"], h, kind=self.cfg.mlp_kind)
-        return x, new_pages
+        return self._mlp(kind, p, self._add(x, a)), new_pages
 
     def decode_step_paged(self, params, pages, token, pos, block_tables,
                           active) -> tuple[jax.Array, PyTree]:
@@ -543,11 +625,10 @@ class DecoderLM:
     def unit_layout(self) -> UnitLayout:
         cfg = self.cfg
         entries = [UnitEntry("embed", "embed", None)]
-        gi = 0
-        for group, _kind, n in cfg.runs():
-            for i in range(n):
-                entries.append(UnitEntry(f"layer_{gi + i}", group, i))
-            gi += n
+        for group, _kind, lo, hi in cfg.pieces():
+            for i in range(lo, hi):
+                entries.append(UnitEntry(f"layer_{len(entries) - 1}", group,
+                                         i))
         if cfg.mtp:
             entries.append(UnitEntry("mtp", "mtp", None))
         entries.append(UnitEntry("head", "head", None))
@@ -557,7 +638,9 @@ class DecoderLM:
     def _block_param_count(self, kind: str) -> int:
         cfg = self.cfg
         d, hd = cfg.d_model, cfg.hd
-        if cfg.mla is not None:
+        if kind == "mamba":
+            attn = mamba2_mixer_param_count(cfg.mamba)
+        elif cfg.mla is not None:
             attn = mla_mod.mla_param_count(cfg.mla, d)
         else:
             attn = d * hd * (cfg.n_heads + 2 * cfg.n_kv_heads) \
@@ -604,10 +687,12 @@ class DecoderLM:
         return n
 
     def _block_fwd_flops(self, kind: str, tokens: int, seq: int,
-                         kv_len: int) -> float:
+                         kv_len: int, decode: bool = False) -> float:
         cfg = self.cfg
         d, hd = cfg.d_model, cfg.hd
-        if cfg.mla is not None:
+        if kind == "mamba":
+            attn = mamba2_mixer_fwd_flops(cfg.mamba, tokens, decode=decode)
+        elif cfg.mla is not None:
             attn = mla_mod.mla_fwd_flops(cfg.mla, d, tokens, kv_len)
         else:
             proj = d * hd * (cfg.n_heads + 2 * cfg.n_kv_heads) \
@@ -637,13 +722,12 @@ class DecoderLM:
             tokens, kv_len, s = batch * 1, seq, seq
         out = [("embed", float(cfg.vocab * cfg.d_model), 2.0 * tokens
                 * cfg.d_model)]
-        gi = 0
-        for _group, kind, cnt in cfg.runs():
+        for _group, kind, lo, hi in cfg.pieces():
             per_p = float(self._block_param_count(kind))
-            per_f = self._block_fwd_flops(kind, tokens, s, kv_len)
-            for i in range(cnt):
-                out.append((f"layer_{gi + i}", per_p, per_f))
-            gi += cnt
+            per_f = self._block_fwd_flops(kind, tokens, s, kv_len,
+                                          decode=mode != "train")
+            for _ in range(lo, hi):
+                out.append((f"layer_{len(out) - 1}", per_p, per_f))
         if cfg.mtp:
             p = float(self._block_param_count(cfg.runs()[-1][1])
                       + 2 * cfg.d_model * cfg.d_model)

@@ -79,6 +79,30 @@ def test_flash_attention_grad_sweep(b, sq, nq, nkv, hd, dtype):
         _close(g, w, tol)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_scale(dtype):
+    """``scale=None`` is the op as it was (scores by ``hd ** -0.5``, to
+    the bit); ``scale=1/64`` at head 64 (granite-4.0-h's attention
+    multiplier) agrees with the einsum path and the oracle at that scale,
+    forward and backward."""
+    from repro.models.layers import gqa_attention
+    q, k, v = _qkv(1, 256, 32, 8, 64, dtype)
+    assert bool((flash_attention(q, k, v, scale=None)
+                 == flash_attention(q, k, v, scale=64 ** -0.5)).all())
+    scaled = functools.partial(flash_attention, scale=1 / 64)
+    oracle = functools.partial(attention_ref, scale=1 / 64)
+    einsum = functools.partial(gqa_attention, scale=1 / 64)
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    _close(scaled(q, k, v), oracle(q, k, v), tol)
+    _close(einsum(q, k, v), oracle(q, k, v), tol)
+    for g, w in zip(_grads(scaled, q, k, v), _grads(oracle, q, k, v),
+                    strict=True):
+        _close(g, w, tol)
+    assert not np.allclose(np.asarray(scaled(q, k, v), np.float32),
+                           np.asarray(flash_attention(q, k, v), np.float32),
+                           atol=tol)
+
+
 def test_flash_attention_under_worker_vmap():
     """Forward and gradients vmapped over a leading worker axis, as the
     DreamDDP step runs them, agree with the oracle worker by worker."""

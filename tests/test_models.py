@@ -114,6 +114,7 @@ def test_full_config_param_counts():
         "qwen3-moe-30b-a3b": (29e9, 32e9),
         "deepseek-v3-671b": (650e9, 700e9),
         "whisper-medium": (0.7e9, 0.85e9),
+        "granite-4.0-h-micro": (3.1e9, 3.3e9),
     }
     for aid, (lo, hi) in expect.items():
         n = get_arch(aid).make_model().param_count()
@@ -167,9 +168,9 @@ def test_flash_path_loss_and_grads_match_einsum(monkeypatch):
     loss, grads = loss_and_grads()
     calls = []
 
-    def flash(q, k, v, positions):
+    def flash(q, k, v, positions, **kw):
         calls.append(q.shape)
-        return flash_attention(q, k, v)
+        return flash_attention(q, k, v, **kw)
 
     monkeypatch.setattr(transformer, "causal_self_attention", flash)
     flash_loss, flash_grads = loss_and_grads()
@@ -190,9 +191,9 @@ def _count_causal_calls(monkeypatch):
     calls = []
     real = transformer.causal_self_attention
 
-    def spy(q, k, v, positions):
+    def spy(q, k, v, positions, **kw):
         calls.append(q.shape)
-        return real(q, k, v, positions)
+        return real(q, k, v, positions, **kw)
 
     monkeypatch.setattr(transformer, "causal_self_attention", spy)
     return calls

@@ -25,6 +25,7 @@ from jax.sharding import PartitionSpec as P
 from repro.api import JobConfig, Session
 from repro.configs import get_arch
 from repro.configs.granite_3_2b import CONFIG as GRANITE
+from repro.configs.granite_4_0_h_micro import CONFIG as GRANITE_H
 from repro.kernels.paged_attention.kernel import paged_attention_fwd
 from repro.models.transformer import DecoderLM
 from repro.optim import make_optimizer
@@ -117,6 +118,39 @@ def _granite_step(workers, seq, place):
     step = jax.jit(make_train_step(model, opt, plan, phase, cfg=scfg),
                    donate_argnums=0)
     return step, state, batch
+
+
+def test_hybrid_phase_step_fits_v5e(one_chip):
+    """granite-4.0-h-micro's phase step at published widths, one Mamba-2
+    layer before one attention layer, W=1, seq 4096, compiles for the
+    chip within its HBM: attention on the flash kernels at the scale
+    1/64, the mixer's ops under ``mamba_mixer`` and the chunked SSD's
+    under ``ssd``, forward, remat and backward."""
+    model = DecoderLM(replace(GRANITE_H, n_layers=2,
+                              layer_types=("mamba", "attention")))
+    sess = Session(JobConfig(arch=GRANITE_H.name, smoke=False, workers=1,
+                             period=2, seq=4096, batch_per_worker=1),
+                   model=model)
+    plan, scfg = sess.plan, sess.step_config
+    opt = make_optimizer("adam")
+    state = jax.eval_shape(lambda: init_train_state(
+        model, opt, jax.random.PRNGKey(0), 1, cfg=scfg))
+    place = lambda s: _on_chip(one_chip, s.shape, s.dtype)  # noqa: E731
+    state = jax.tree.map(place, state)
+    batch = {k: _on_chip(one_chip, (1, 1, 4096), jnp.int32)
+             for k in ("tokens", "labels")}
+    step = jax.jit(make_train_step(model, opt, plan, 0, cfg=scfg),
+                   donate_argnums=0)
+    compiled = step.lower(state, batch).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    hlo = compiled.as_text()
+    assert len(_flash_kernels(hlo)) == 3
+    ssd = re.findall(r'op_name="[^"]*/mamba_mixer/ssd/[^"]*"', hlo)
+    assert any("transpose(jvp(fwd))" in o and "rematted" not in o
+               for o in ssd)
+    assert any("rematted_computation" in o for o in ssd)
+    assert any("transpose(" not in o for o in ssd)
 
 
 def test_granite_phase_step_fits_v5e(one_chip):
